@@ -28,6 +28,9 @@ def main() -> None:
                          "benchmarks/BENCH_engine.json baseline")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_async, bench_engine, bench_kernels,
                             bench_losslessness, bench_regression,
                             bench_roofline, bench_scalability,

@@ -46,6 +46,29 @@ _INF = math.inf
 _RING_ALIAS_PRIMS = {"dynamic_update_slice", "select_n", "convert_element_type"}
 
 
+def _call_body(eqn):
+    """The body of a call-like eqn, or None.
+
+    A call (``jit`` — named ``pjit`` before JAX 0.7 — ``closed_call``,
+    custom-derivative calls) carries a closed jaxpr whose invars and
+    outvars map one to one onto the eqn's, so the analyses inline it with
+    the caller's facts.  Recognized by that shape, not by a primitive
+    name, so a renamed call primitive cannot fail the proofs closed.  A
+    scan also carries a closed body of matching arity, but its outputs
+    are the fixpoint of many iterations (``num_carry``), so it is not a
+    call."""
+    if "num_carry" in eqn.params:
+        return None
+    for key in ("jaxpr", "call_jaxpr"):
+        sub = eqn.params.get(key)
+        inner = getattr(sub, "jaxpr", None)      # ClosedJaxpr only
+        if (inner is not None and hasattr(inner, "eqns")
+                and len(inner.invars) == len(eqn.invars)
+                and len(inner.outvars) == len(eqn.outvars)):
+            return inner
+    return None
+
+
 # ---------------------------------------------------------------------------
 # interval abstract interpretation over index arithmetic
 # ---------------------------------------------------------------------------
@@ -91,7 +114,7 @@ class _Intervals:
 
     At the top level, integer invars are assumed nonnegative (the
     engine's documented precondition for step counters / delay
-    schedules); sub-jaxprs (``pjit`` bodies) are seeded from the caller's
+    schedules); call bodies (:func:`_call_body`) are seeded from the caller's
     intervals instead — never re-assumed, since an inner invar may bind a
     possibly-negative intermediate like ``t - delay``.  Comparisons
     produce boolean intervals ([0,0] false / [1,1] true / [0,1] unknown)
@@ -189,14 +212,11 @@ class _Intervals:
             out = ins[0]
         elif name == "neg":
             out = (-ins[0][1], -ins[0][0])
-        elif name == "pjit":
-            sub = eqn.params.get("jaxpr")
-            if sub is not None:
-                seed = dict(zip(sub.jaxpr.invars, ins))
-                inner = _Intervals(sub.jaxpr, seed=seed)
-                for ov, v in zip(eqn.outvars, sub.jaxpr.outvars):
-                    self._set(ov, inner.get(v))
-                return
+        elif (body := _call_body(eqn)) is not None:
+            inner = _Intervals(body, seed=dict(zip(body.invars, ins)))
+            for ov, v in zip(eqn.outvars, body.outvars):
+                self._set(ov, inner.get(v))
+            return
         if out is None:
             out = (-_INF, _INF)
         for v in eqn.outvars:
@@ -273,8 +293,8 @@ def ring_audit(closed_jaxpr, tau: int) -> List[RingAudit]:
 
 
 class _RingWalk:
-    """Collect ring writes/reads/gates across a body and its pjit
-    sub-jaxprs, propagating the buffer-alias set and index intervals
+    """Collect ring writes/reads/gates across a body and its call
+    bodies (:func:`_call_body`), propagating the buffer-alias set and index intervals
     through call boundaries.  Positions are a global eqn counter so
     program order (write-before-read) survives the inlining."""
 
@@ -312,11 +332,7 @@ class _RingWalk:
                 aliases.add(eqn.outvars[0])
             elif name in ("convert_element_type", "copy", "reshape"):
                 aliases.add(eqn.outvars[0])
-            elif name == "pjit":
-                sub = eqn.params.get("jaxpr")
-                if sub is None:
-                    continue
-                inner = sub.jaxpr
+            elif (inner := _call_body(eqn)) is not None:
                 seed = {v: iv.get(a)
                         for v, a in zip(inner.invars, eqn.invars)}
                 inner_iv = _Intervals(inner, seed=seed)

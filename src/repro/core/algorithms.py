@@ -464,6 +464,9 @@ class TrainResult:
     # supervised runs (supervise=True) record every divergence rollback
     # here (core.supervisor.HealEvent dicts); empty list = no heals
     heals: Optional[List[dict]] = None
+    # fused runs: the FusedEngine that ran the epochs (its compiled
+    # epochs, kernel routing and placed data; a ServeEngine can wrap it)
+    engine: object = None
 
 
 def _eval(problem, w, x, y):
@@ -740,7 +743,7 @@ def _train_deep_fused(problem, x, y, layout, algo, epochs, lr, batch, seed,
                             keep_last=keep_last)
     params = eng.unpack_deep(pq)
     return TrainResult(w=np.asarray(params.head), history=hist,
-                       params=params)
+                       params=params, engine=eng)
 
 
 def _train_fused(problem, x, y, layout, algo, epochs, lr, batch, seed,
@@ -830,7 +833,7 @@ def _train_fused(problem, x, y, layout, algo, epochs, lr, batch, seed,
         if checkpoint_dir is not None:
             save_checkpoint(checkpoint_dir, _state(), step=ep + 1,
                             keep_last=keep_last)
-    return TrainResult(w=eng.unpack_w(wq), history=hist)
+    return TrainResult(w=eng.unpack_w(wq), history=hist, engine=eng)
 
 
 def accuracy(w, x, y) -> float:

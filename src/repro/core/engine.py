@@ -131,7 +131,7 @@ from repro.core.secure_agg import (secure_psum, secure_psum_hier,
                                    secure_psum_ring_members)
 from repro.kernels import vfl_grad as _vg
 from repro.sharding.api import PartyMesh, shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,23 +171,27 @@ def party_widths(layout: PartyLayout) -> np.ndarray:
     return np.asarray([hi - lo for lo, hi in layout.bounds], np.int64)
 
 
-def pack_features(x: np.ndarray, layout: PartyLayout) -> jax.Array:
+# The pack_* helpers build host (numpy) arrays; ``FusedEngine.place`` puts
+# them on the device(s), so a mesh-bound engine ships each party's block
+# straight to its own device instead of staging everything on device 0.
+
+def pack_features(x: np.ndarray, layout: PartyLayout) -> np.ndarray:
     """Stack per-party feature blocks, zero-padded to the widest block."""
     n = x.shape[0]
     dp = int(party_widths(layout).max())
     xs = np.zeros((layout.q, n, dp), np.float32)
     for p, (lo, hi) in enumerate(layout.bounds):
         xs[p, :, : hi - lo] = x[:, lo:hi]
-    return jnp.asarray(xs)
+    return xs
 
 
-def pack_vec(v: np.ndarray, layout: PartyLayout) -> jax.Array:
+def pack_vec(v: np.ndarray, layout: PartyLayout) -> np.ndarray:
     """(d,) coordinate vector -> (q, dp) party-stacked, zero-padded."""
     dp = int(party_widths(layout).max())
     out = np.zeros((layout.q, dp), np.float32)
     for p, (lo, hi) in enumerate(layout.bounds):
         out[p, : hi - lo] = np.asarray(v)[lo:hi]
-    return jnp.asarray(out)
+    return out
 
 
 def unpack_vec(vq, layout: PartyLayout) -> np.ndarray:
@@ -249,8 +253,7 @@ def pack_deep_params(params, layout: PartyLayout):
     w2q = np.stack([np.asarray(w, np.float32) for w in params.enc_w2])
     head = np.asarray(params.head, np.float32)
     headq = np.tile(head[None, :], (q, 1))
-    return (jnp.asarray(w1q), jnp.asarray(b1q), jnp.asarray(w2q),
-            jnp.asarray(headq))
+    return w1q, b1q, w2q, headq
 
 
 def unpack_deep_params(pq, layout: PartyLayout):
@@ -266,7 +269,7 @@ def unpack_deep_params(pq, layout: PartyLayout):
                          jnp.asarray(headq[0]))
 
 
-def pack_mask(layout: PartyLayout, active_only: bool = False) -> jax.Array:
+def pack_mask(layout: PartyLayout, active_only: bool = False) -> np.ndarray:
     """(q, dp) update mask: layout's trainable blocks minus the padding."""
     dp = int(party_widths(layout).max())
     mask = np.zeros((layout.q, dp), np.float32)
@@ -274,7 +277,7 @@ def pack_mask(layout: PartyLayout, active_only: bool = False) -> jax.Array:
     for p in parties:
         lo, hi = layout.bounds[p]
         mask[p, : hi - lo] = 1.0
-    return jnp.asarray(mask)
+    return mask
 
 
 # ---------------------------------------------------------------------------
@@ -360,17 +363,6 @@ class FusedEngine:
         self.cfg = cfg
         self.q = layout.q
         self.n = int(np.asarray(x).shape[0])
-        self.xs = pack_features(np.asarray(x), layout)      # (q, n, dp)
-        self.dp = int(self.xs.shape[2])
-        self.y = jnp.asarray(y, jnp.float32)
-        self.maskq = pack_mask(layout, active_only)
-        # (q,) per-party trainability flag for the deep epochs' non-feature
-        # parameters (b1/w2 have no coordinate rows for maskq to act on):
-        # active_only freezes passive parties' encoders, the AFSVRG-VP
-        # analogue (deep_vfl's freeze_passive).
-        self.trainq = jnp.asarray(
-            [1.0 if (not active_only or p < layout.m) else 0.0
-             for p in range(layout.q)], jnp.float32)
         # ``mesh`` is either a plain jax Mesh (flat layout: one party per
         # slot, the historical contract) or a PartyMesh decoupling the
         # logical party axis from the physical one (q = slots × pps, with
@@ -402,6 +394,17 @@ class FusedEngine:
             self.pmesh = None
             self.mesh = mesh
         self._use_shard_map = self.mesh is not None
+        self.xs = self.place(pack_features(np.asarray(x), layout))  # (q,n,dp)
+        self.dp = int(self.xs.shape[2])
+        self.y = self.place(np.asarray(y, np.float32), party=False)
+        self.maskq = self.place(pack_mask(layout, active_only))
+        # (q,) per-party trainability flag for the deep epochs' non-feature
+        # parameters (b1/w2 have no coordinate rows for maskq to act on):
+        # active_only freezes passive parties' encoders, the AFSVRG-VP
+        # analogue (deep_vfl's freeze_passive).
+        self.trainq = self.place(np.asarray(
+            [1.0 if (not active_only or p < layout.m) else 0.0
+             for p in range(layout.q)], np.float32))
         pm = self.pmesh
         self._slots = pm.slots if pm is not None else layout.q
         self._pps = pm.parties_per_slot if pm is not None else 1
@@ -420,11 +423,16 @@ class FusedEngine:
         if self._data_axis is not None:
             env.append((self._data_axis, self._ddp))
         self._axis_env = tuple(env)
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu and cfg.interpret:
+            # The Pallas interpreter on a chip runs the kernel body op by
+            # op: correct numbers, none of the chip's kernel behaviour.
+            raise ValueError("interpret=True is refused on a TPU backend; "
+                             "leave EngineConfig.interpret unset there")
         kern = cfg.use_kernel
-        self._kernel = (jax.default_backend() == "tpu") if kern is None else kern
+        self._kernel = on_tpu if kern is None else kern
         interp = cfg.interpret
-        self._interpret = (jax.default_backend() != "tpu") if interp is None \
-            else interp
+        self._interpret = (not on_tpu) if interp is None else interp
         self._jitted = {}
         # epoch name -> PartyProgram, recorded by _bind at trace time for
         # the static-analysis subsystem (repro.analysis)
@@ -3460,14 +3468,28 @@ class FusedEngine:
 
     # -- boundary helpers ----------------------------------------------------
 
+    def place(self, a, party: bool = True) -> jax.Array:
+        """Put a host or device array where the epochs read it.
+
+        Bound to a mesh, a party-stacked array (leading logical-party
+        axis) is sharded on the party axis — each device holds only its
+        own parties' rows, in the contiguous blocks the slot mapping of
+        :meth:`_bind` reads — and anything else (``party=False``) is
+        replicated.  Without a mesh it goes to the default device."""
+        if self.mesh is None:
+            return jnp.asarray(a)
+        spec = P(self.cfg.axis) if party else P()
+        return jax.device_put(a, NamedSharding(self.mesh, spec))
+
     def pack_w(self, w) -> jax.Array:
-        return pack_vec(np.asarray(w), self.layout)
+        return self.place(pack_vec(np.asarray(w), self.layout))
 
     def unpack_w(self, wq) -> np.ndarray:
         return unpack_vec(wq, self.layout)
 
     def pack_deep(self, params):
-        return pack_deep_params(params, self.layout)
+        return tuple(self.place(a)
+                     for a in pack_deep_params(params, self.layout))
 
     def unpack_deep(self, pq):
         return unpack_deep_params(pq, self.layout)

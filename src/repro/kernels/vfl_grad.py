@@ -196,10 +196,15 @@ def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
             g_ref[...] = acc.astype(g_ref.dtype)
 
 
-def vfl_grad(xb, w, theta, lam=0.0, *, block_b: int = 128,
-             block_d: int = 128, interpret: bool = True, mode: str = "fused",
+def vfl_grad(xb, w, theta, lam=0.0, *, interpret: bool, block_b: int = 128,
+             block_d: int = 128, mode: str = "fused",
              denom: int | None = None, split: int | None = None):
     """xb: (B, D); w: (D,), (D, Mw) or None; theta: (B,), (B, Mθ) or None.
+
+    ``interpret`` has no default: True runs the kernel body in the Pallas
+    interpreter (off-TPU validation), False compiles it through Mosaic.
+    Every caller states which, so a chip run can never drift into the
+    interpreter unnoticed.
 
     Returns ``(z, g)`` with z = xb @ w fully reduced in-kernel (shape (B,)
     or (B, Mw)) and g = xbᵀθ/denom + λw (shape (D,) or (D, Mθ)).  ``denom``

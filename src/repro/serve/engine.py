@@ -119,7 +119,8 @@ class ServeEngine:
         if x is None:
             self.xs = engine.xs
         else:
-            self.xs = pack_features(np.asarray(x), engine.layout)
+            self.xs = engine.place(pack_features(np.asarray(x),
+                                                 engine.layout))
         self.n = int(self.xs.shape[1])
         self.dp = int(self.xs.shape[2])
         self.max_batch = int(max_batch)
@@ -131,8 +132,8 @@ class ServeEngine:
         # output is exactly the passive sum.  Party-stacked so the same
         # program works under flat vmap/shard_map and packed PartyMesh
         # bindings without any axis-index arithmetic.
-        self._pfq = jnp.asarray(
-            [0.0] + [1.0] * (self.q - 1), jnp.float32)
+        self._pfq = engine.place(np.asarray(
+            [0.0] + [1.0] * (self.q - 1), np.float32))
         self._base_key = jax.random.PRNGKey(seed)
         self.version = 0
         self._counter = 0          # masked dispatches within this version
@@ -154,7 +155,7 @@ class ServeEngine:
         the old passive blocks and is no longer a hit (linear entries
         exactly one version behind stay repairable via the masked delta
         aggregation while ``delta_refresh`` holds)."""
-        wq = (jnp.asarray(w, jnp.float32)
+        wq = (self.eng.place(jnp.asarray(w, jnp.float32))
               if np.asarray(w).ndim == 2 else self.eng.pack_w(w))
         if wq.shape != (self.q, self.dp):
             raise ValueError(f"weights shape {wq.shape} != (q, dp) = "
@@ -182,7 +183,7 @@ class ServeEngine:
             raise ValueError("deep params must be the 4-tuple "
                              "(w1q, b1q, w2q, headq)")
         had = self._wq is not None or self._pq is not None
-        self._pq = tuple(jnp.asarray(a) for a in pq)
+        self._pq = tuple(self.eng.place(a) for a in pq)
         self._wq = None
         self._prev_wq = None
         self.deep = True

@@ -41,26 +41,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _STATE = threading.local()
 
 
-# ---------------------------------------------------------------------------
-# shard_map version shim
-# ---------------------------------------------------------------------------
-# Newer jax exports ``jax.shard_map`` with a ``check_vma`` kwarg; older
-# releases (e.g. 0.4.x, this container) keep it in ``jax.experimental`` with
-# the equivalent ``check_rep``.  Every module in the repo imports shard_map
-# from here so the call sites can use one spelling.
-
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map_new  # type: ignore[attr-defined]
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map_new(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma)
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    """``jax.shard_map`` with ``check_vma`` off unless asked for — the
+    one spelling every shard_map call site in the repo uses."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -167,6 +167,24 @@ def test_kernel_routing_matches_jnp(ds, layout, prob):
     np.testing.assert_allclose(w_k, w_j, atol=1e-5, rtol=0)
 
 
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+def test_interpret_routing_by_backend(ds, layout, prob, monkeypatch,
+                                      backend):
+    """Unset, the kernel and interpreter follow the backend; on a TPU an
+    explicit ``interpret=True`` is refused rather than run."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    eng = FusedEngine(prob, ds.x_train, ds.y_train, layout, EngineConfig())
+    assert (eng._kernel, eng._interpret) == (backend == "tpu",
+                                             backend != "tpu")
+    cfg = EngineConfig(use_kernel=True, interpret=True)
+    if backend == "tpu":
+        with pytest.raises(ValueError, match="refused on a TPU"):
+            FusedEngine(prob, ds.x_train, ds.y_train, layout, cfg)
+    else:
+        eng = FusedEngine(prob, ds.x_train, ds.y_train, layout, cfg)
+        assert eng._kernel and eng._interpret
+
+
 def test_delayed_fused_matches_staleness_reference(ds, layout, prob):
     tau, lr, epochs, seed = 4, 0.3, 3, 0
     delays = staleness.party_delays(layout, D, tau, seed=seed)

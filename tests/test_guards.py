@@ -135,8 +135,18 @@ def test_deep_guarded_fused_matches_oracle(ds, layout, trace, algo, secure):
                   + list(p_ref.enc_w2) + [p_ref.head])
     fus_leaves = (list(p_fused.enc_w1) + list(p_fused.enc_b1)
                   + list(p_fused.enc_w2) + [p_fused.head])
+    # Each leaf is pinned at 1e-5 of its own largest entry (never below
+    # 1e-5): the head grows to |57| here, and the fused epoch and the
+    # oracle sum in different orders, which moves f32 results by ~1e-4 at
+    # that size (measured 9.5e-5 on the head, sgd-off and sgd-ring) — a
+    # few ulps, not a wrong result.  The scale is the leaf's, not each
+    # entry's: the head's 0.06 entry moves by 3.8e-5 under the same
+    # reordering, since every entry's update sums terms of the leaf's size.
     for a, b in zip(fus_leaves, ref_leaves):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        b = np.asarray(b)
+        scale = max(1.0, float(np.abs(b).max()))
+        np.testing.assert_allclose(np.asarray(a), b, rtol=0,
+                                   atol=1e-5 * scale)
     _assert_health_pinned(h_fused, h_ref)
     assert not poisoned_steps(h_fused).any()
 

@@ -3,7 +3,8 @@ host devices (the main pytest process keeps the real 1-device topology).
 
 Covers: BUM gradient broadcast, secure-psum exactness + both schedules,
 sharded-MoE == reference, vocab-parallel loss == plain CE, sequence-sharded
-decode attention == single-shard decode.
+decode attention == single-shard decode; and, separately, the fused
+engine's one-party-per-device binding and data placement.
 """
 import os
 import subprocess
@@ -23,7 +24,9 @@ SCRIPT = textwrap.dedent("""
     from repro.vfl.heads import vocab_parallel_loss
     from repro.vfl.embed import secure_vocab_embed
 
-    mesh = jax.make_mesh((1, 2, 4), ("pod", "data", "model"))
+    # Auto axes: the LM stack places values with with_sharding_constraint
+    mesh = jax.make_mesh((1, 2, 4), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     rt = Runtime(mesh=mesh, batch_axes=("data",), attn_chunk=16,
                  loss_chunk=8)
     key = jax.random.PRNGKey(0)
@@ -110,10 +113,20 @@ SCRIPT = textwrap.dedent("""
     assert agree >= 0.95, agree
     print("sharded decode ok")
 
-    # --- fused engine: shard_map party binding == sequential reference ---
+    print("ALL-MULTIDEVICE-OK")
+""")
+
+
+# The fused engine's shard_map party binding, on its own so that a failure
+# in the LM section above cannot hide it: one party per device, the data
+# placed on the party axis at construction, results == sequential oracle.
+ENGINE_SCRIPT = textwrap.dedent("""
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import Mesh
     from repro.core import algorithms as alg
     from repro.core.engine import EngineConfig, FusedEngine
     from repro.core.losses import logistic_l2
+
     rngd = np.random.default_rng(0)
     xd = rngd.standard_normal((256, 26)).astype(np.float32)
     yd = np.sign(rngd.standard_normal(256)).astype(np.float32)
@@ -121,25 +134,45 @@ SCRIPT = textwrap.dedent("""
     prob = logistic_l2()
     kk = jax.random.PRNGKey(0)
     maskd = jnp.asarray(layout.update_mask(26, False))
-    w_ref = alg.sgd_epoch(prob, jnp.zeros(26), jnp.asarray(xd),
-                          jnp.asarray(yd), 0.3, maskd, kk, 32, 8)
-    eng = FusedEngine(prob, xd, yd, layout,
-                      EngineConfig(secure="two_tree"), mesh=mesh)
-    assert eng._use_shard_map
-    w_eng = eng.unpack_w(eng.sgd_epoch(eng.pack_w(np.zeros(26)), 0.3, kk,
-                                       32, 8))
-    assert np.allclose(w_eng, np.asarray(w_ref), atol=1e-5), \
-        np.abs(w_eng - np.asarray(w_ref)).max()
-    print("fused engine shard_map ok")
-    print("ALL-MULTIDEVICE-OK")
+    w_ref = np.asarray(alg.sgd_epoch(prob, jnp.zeros(26), jnp.asarray(xd),
+                                     jnp.asarray(yd), 0.3, maskd, kk, 32, 8))
+    meshes = {"make_mesh": jax.make_mesh((1, 2, 4), ("pod", "data", "model")),
+              "Mesh": Mesh(np.asarray(jax.devices()[:4]), ("model",))}
+    for mname, mesh in meshes.items():
+        for secure in ("two_tree", "ring"):
+            eng = FusedEngine(prob, xd, yd, layout,
+                              EngineConfig(secure=secure), mesh=mesh)
+            assert eng._use_shard_map
+            for arr in (eng.xs, eng.maskq):
+                rows = {s.index[0] for s in arr.addressable_shards}
+                assert len(rows) == 4, (mname, rows)   # one party per shard
+                assert all(s.data.shape[0] == 1
+                           for s in arr.addressable_shards)
+            assert eng.y.sharding.is_fully_replicated
+            wq = eng.pack_w(np.zeros(26))
+            assert wq.sharding == eng.maskq.sharding, wq.sharding
+            w_eng = eng.unpack_w(eng.sgd_epoch(wq, 0.3, kk, 32, 8))
+            assert np.allclose(w_eng, w_ref, atol=1e-5), \\
+                (mname, secure, np.abs(w_eng - w_ref).max())
+    print("ALL-ENGINE-MESH-OK")
 """)
+
+
+def _run_8_devices(script):
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    return subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=1200)
 
 
 @pytest.mark.slow
 def test_multidevice_semantics():
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                       capture_output=True, text=True, timeout=1200)
+    r = _run_8_devices(SCRIPT)
     assert "ALL-MULTIDEVICE-OK" in r.stdout, r.stdout + "\n" + r.stderr
+
+
+@pytest.mark.slow
+def test_fused_engine_shard_map_places_parties():
+    r = _run_8_devices(ENGINE_SCRIPT)
+    assert "ALL-ENGINE-MESH-OK" in r.stdout, r.stdout + "\n" + r.stderr
