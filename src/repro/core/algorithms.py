@@ -465,7 +465,9 @@ class TrainResult:
     # here (core.supervisor.HealEvent dicts); empty list = no heals
     heals: Optional[List[dict]] = None
     # fused runs: the FusedEngine that ran the epochs (its compiled
-    # epochs, kernel routing and placed data; a ServeEngine can wrap it)
+    # epochs, kernel routing and placed data; a ServeEngine can wrap it).
+    # It keeps the packed dataset resident on the device(s) for as long as
+    # the result is held: drop the result, or set this to None, to free it.
     engine: object = None
 
 

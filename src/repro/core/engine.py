@@ -163,6 +163,31 @@ class EngineConfig:
     donate: bool = False
 
 
+class F32Program:
+    """A jitted engine program traced with every contraction at f32.
+
+    On a TPU, XLA's f32 dot at the default matmul precision rounds both
+    operands to bf16 (unit roundoff 2^-9).  The engine is pinned to f32
+    sequential oracles, so each program is traced (and lowered) under
+    ``jax.default_matmul_precision("highest")``: the X-block routes, the
+    deep head and the objective all keep f32 operands, on every backend.
+    Other attributes (``_cache_size`` …) are the jitted function's."""
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+
+    def __call__(self, *args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return self.jitted(*args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return self.jitted.lower(*args, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self.jitted, name)
+
+
 # ---------------------------------------------------------------------------
 # vertical packing: (n, d) features -> (q, n, dp) padded party blocks
 # ---------------------------------------------------------------------------
@@ -724,7 +749,7 @@ class FusedEngine:
         if name not in self._jitted:
             self._building = name
             try:
-                self._jitted[name] = builder()
+                self._jitted[name] = F32Program(builder())
             finally:
                 self._building = None
         return self._jitted[name]
@@ -3502,10 +3527,11 @@ class FusedEngine:
         replicated head is counted once."""
         prob = self.problem
         w1q, b1q, w2q, headq = pq
-        h = jnp.tanh(jnp.einsum("qnd,qdh->qnh", self.xs, w1q)
-                     + b1q[:, None, :])
-        z = jnp.einsum("qnh,qhr->nr", h, w2q)
-        logit = z @ headq[0]
+        with jax.default_matmul_precision("highest"):   # as F32Program
+            h = jnp.tanh(jnp.einsum("qnd,qdh->qnh", self.xs, w1q)
+                         + b1q[:, None, :])
+            z = jnp.einsum("qnh,qhr->nr", h, w2q)
+            logit = z @ headq[0]
         regv = (jnp.sum(prob.reg(w1q)) + jnp.sum(prob.reg(b1q))
                 + jnp.sum(prob.reg(w2q)) + jnp.sum(prob.reg(headq[0])))
         return float(jnp.mean(prob.loss(logit, self.y)) + prob.lam * regv)
@@ -3516,6 +3542,7 @@ class FusedEngine:
         The padded coordinates are zero and every shipped regularizer maps
         0 → 0, so summing ``reg`` over the padded stack is exact."""
         prob = self.problem
-        agg = jnp.einsum("qnd,qd->n", self.xs, wq)
+        with jax.default_matmul_precision("highest"):   # as F32Program
+            agg = jnp.einsum("qnd,qd->n", self.xs, wq)
         return float(jnp.mean(prob.loss(agg, self.y))
                      + prob.lam * jnp.sum(prob.reg(wq)))

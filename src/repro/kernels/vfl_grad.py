@@ -19,7 +19,8 @@ current iterate and the snapshot, M = 2) — in a *single* HBM pass over X:
 Both reductions complete **in-kernel**: z is accumulated across feature
 tiles in a full-minibatch VMEM scratch (so callers never re-sum partials on
 the host), g across batch tiles in a per-feature-tile scratch.  Inputs may
-be bf16; all accumulation is f32 in VMEM.
+be bf16; products take f32 operands (``precision=HIGHEST``) and all
+accumulation is f32 in VMEM.
 
 Grid (nD, nB) — batch tiles minor-most (sequential) so the g accumulator
 carries across batch tiles for a fixed feature tile; the z accumulator is a
@@ -74,6 +75,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# Every in-kernel dot states its precision.  Given none, Mosaic's f32 dot
+# on a TPU rounds both operands to bf16 (unit roundoff 2^-9), which would
+# put the chip path a bf16 rounding away from the f32 oracles it is pinned
+# to; HIGHEST keeps f32 operands on the MXU.  Off the chip (interpret
+# mode) f32 dots are f32 either way.
+_F32 = jax.lax.Precision.HIGHEST
+
+
 def _round_up(v: int, m: int) -> int:
     return (v + m - 1) // m * m
 
@@ -126,7 +135,8 @@ def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
     if fwd:
         def _z_work():
             # forward partials for this (feature, batch) tile: rank-k MXU
-            zt = jnp.dot(x, w, preferred_element_type=jnp.float32)
+            zt = jnp.dot(x, w, precision=_F32,
+                         preferred_element_type=jnp.float32)
             if z_acc is None:
                 # nD == 1: one feature pass computes the full z — write
                 # the output block directly, no accumulator round-trip
@@ -162,7 +172,7 @@ def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
         # revisiting contract the z path relies on.
         def _g_once():
             th = theta_ref[...].astype(jnp.float32)       # (Bb, Mθ)
-            acc = jnp.dot(x.T, th,
+            acc = jnp.dot(x.T, th, precision=_F32,
                           preferred_element_type=jnp.float32) / denom
             if use_lamw:
                 acc = acc + lam_ref[0, 0] * w
@@ -180,7 +190,7 @@ def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
         def _g_work():
             th = theta_ref[...].astype(jnp.float32)       # (Bb, Mθ)
             # backward accumulate: XᵀΘ, f32 in VMEM
-            g_acc[...] += jnp.dot(x.T, th,
+            g_acc[...] += jnp.dot(x.T, th, precision=_F32,
                                   preferred_element_type=jnp.float32)
 
         if nsplit is None:

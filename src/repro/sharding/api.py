@@ -188,12 +188,19 @@ def current_mesh() -> Mesh:
 
 
 def shard(x, *spec):
-    """with_sharding_constraint against the active runtime's mesh."""
+    """Place ``x`` as ``spec`` on the active runtime's mesh.
+
+    Auto axes take a ``with_sharding_constraint`` hint; Explicit axes
+    (``jax.make_mesh``'s default) are part of the value's type and are
+    changed with ``jax.sharding.reshard``."""
     rt = getattr(_STATE, "rt", None)
     if rt is None:
         return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(rt.mesh, P(*spec)))
+    sharding = NamedSharding(rt.mesh, P(*spec))
+    if any(t == jax.sharding.AxisType.Explicit
+           for t in rt.mesh.axis_types):
+        return jax.sharding.reshard(x, sharding)
+    return jax.lax.with_sharding_constraint(x, sharding)
 
 
 def single_device_runtime(**kw) -> Runtime:

@@ -185,6 +185,46 @@ def test_interpret_routing_by_backend(ds, layout, prob, monkeypatch,
         assert eng._kernel and eng._interpret
 
 
+def _dot_precisions(jaxpr):
+    from repro.analysis.walkers import sub_jaxprs
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in sub_jaxprs(v):
+                out.extend(_dot_precisions(sub))
+    return out
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+@pytest.mark.parametrize("program", ["sgd", "pipelined_sgd",
+                                     "full_gradient", "deep_sgd"])
+def test_every_contraction_keeps_f32_operands(ds, layout, prob, use_kernel,
+                                              program):
+    """Every dot of an engine program — inside the Pallas kernel and on
+    XLA's route — is traced at HIGHEST precision: given none, a TPU f32
+    dot rounds its operands to bf16 and the chip path drifts from the
+    f32 oracles by about 2^-9."""
+    from repro.core import deep_vfl
+    eng = FusedEngine(prob, ds.x_train, ds.y_train, layout,
+                      EngineConfig(use_kernel=use_kernel))
+    key = jax.random.PRNGKey(0)
+    wq = eng.pack_w(np.zeros(D))
+    pq = eng.pack_deep(deep_vfl.init_deep_vfl(key, layout, D, 8, 4))
+    run = {
+        "sgd": lambda: eng.sgd_epoch(wq, 0.5, key, BATCH, 2),
+        "pipelined_sgd": lambda: eng.pipelined_sgd_epoch(wq, 0.5, key,
+                                                         BATCH, 2),
+        "full_gradient": lambda: eng.full_gradient(wq, key),
+        "deep_sgd": lambda: eng.deep_sgd_epoch(pq, 0.05, key, BATCH, 2),
+    }[program]
+    precisions = _dot_precisions(jax.make_jaxpr(run)().jaxpr)
+    assert precisions
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    assert all(p == highest for p in precisions), precisions
+
+
 def test_delayed_fused_matches_staleness_reference(ds, layout, prob):
     tau, lr, epochs, seed = 4, 0.3, 3, 0
     delays = staleness.party_delays(layout, D, tau, seed=seed)

@@ -24,9 +24,7 @@ SCRIPT = textwrap.dedent("""
     from repro.vfl.heads import vocab_parallel_loss
     from repro.vfl.embed import secure_vocab_embed
 
-    # Auto axes: the LM stack places values with with_sharding_constraint
-    mesh = jax.make_mesh((1, 2, 4), ("pod", "data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
+    mesh = jax.make_mesh((1, 2, 4), ("pod", "data", "model"))
     rt = Runtime(mesh=mesh, batch_axes=("data",), attn_chunk=16,
                  loss_chunk=8)
     key = jax.random.PRNGKey(0)
@@ -85,8 +83,15 @@ SCRIPT = textwrap.dedent("""
     print("secure embed ok")
 
     # --- sequence-sharded decode == full forward next-token ---
+    # The chunked attention's head-splitting reshapes are written for Auto
+    # axes; under Explicit (typed) sharding they would need an
+    # out_sharding each.  This section keeps an Auto mesh.
     from repro.configs.base import get_arch
     cfg = get_arch("stablelm_1_6b").reduced()
+    rt = Runtime(mesh=jax.make_mesh(
+        (1, 2, 4), ("pod", "data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 3),
+        batch_axes=("data",), attn_chunk=16, loss_chunk=8)
     with use_runtime(rt):
         params = model_lib.init_params(cfg, key)
         b, s = 4, 16
