@@ -74,9 +74,13 @@ TOL_SERVE = 2 ** 8 * U32
 TOL_SERVE_WHY = ("one f32 contraction of 4,096 features and a masked sum "
                  "of four partials against a float64 product; the delta "
                  "answer adds a cancelling update to the cached sum")
-TOL_EMULATION = 2 ** 10 * U32
+# Mesh vs emulation on a TPU v5e read 3.9e-7 to 5.5e-7 (about 9 U32) for
+# every leg; with bf16 kernel operands the same check read up to 1.8e-3,
+# so the limit sits well below that fault and above the f32 reading.
+TOL_EMULATION = 2 ** 6 * U32
 TOL_EMULATION_WHY = ("the same kernel and masks on one chip or four, f32 "
-                     "throughout: summation order only")
+                     "operands throughout: measured 3.9e-7 to 5.5e-7 on "
+                     "the chip, f32 summation order only")
 
 
 class SmokeFailure(AssertionError):
