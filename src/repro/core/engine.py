@@ -115,12 +115,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.algorithms import PartyLayout, _batch_indices
 from repro.core.faults import HealthStats, apply_corruption
 from repro.core.losses import Problem
@@ -171,13 +173,24 @@ class F32Program:
     sequential oracles, so each program is traced (and lowered) under
     ``jax.default_matmul_precision("highest")``: the X-block routes, the
     deep head and the objective all keep f32 operands, on every backend.
+    Each call is one ``vfb2.dispatch`` host span naming the program and
+    its scanned steps (0 for a program with no ``steps``).
     Other attributes (``_cache_size`` …) are the jitted function's."""
 
-    def __init__(self, jitted):
+    def __init__(self, jitted, name: str):
         self.jitted = jitted
+        self.name = name
+        params = list(inspect.signature(jitted).parameters)
+        self._steps_at = params.index("steps") if "steps" in params else None
 
     def __call__(self, *args, **kwargs):
-        with jax.default_matmul_precision("highest"):
+        steps = 0
+        if self._steps_at is not None:
+            steps = (args[self._steps_at] if len(args) > self._steps_at
+                     else kwargs.get("steps", 0))
+        with tracing.span(tracing.DISPATCH, program=self.name,
+                          steps=int(steps)), \
+                jax.default_matmul_precision("highest"):
             return self.jitted(*args, **kwargs)
 
     def lower(self, *args, **kwargs):
@@ -186,6 +199,27 @@ class F32Program:
 
     def __getattr__(self, name):
         return getattr(self.jitted, name)
+
+
+def _scoped(name: str):
+    """Run the decorated helper under ``jax.named_scope(name)``: every op
+    it stages carries the name in its metadata (``op_name``), which the
+    profiler's device trace keeps.  A fresh scope per call, as JAX's own
+    scope object is not re-entrant."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+@_scoped("vfb2.sample")
+def _sample_indices(key, n, batch, steps):
+    """The epoch's (steps, batch) minibatch row indices, drawn as the
+    sequential oracles draw them."""
+    return _batch_indices(key, n, batch, steps)
 
 
 # ---------------------------------------------------------------------------
@@ -419,17 +453,28 @@ class FusedEngine:
             self.pmesh = None
             self.mesh = mesh
         self._use_shard_map = self.mesh is not None
-        self.xs = self.place(pack_features(np.asarray(x), layout))  # (q,n,dp)
+        with tracing.span("vfb2.engine.build", q=layout.q, rows=self.n):
+            with tracing.span("vfb2.engine.pack"):
+                xs = pack_features(np.asarray(x), layout)      # (q,n,dp)
+                maskq = pack_mask(layout, active_only)
+                # (q,) per-party trainability flag for the deep epochs'
+                # non-feature parameters (b1/w2 have no coordinate rows
+                # for maskq to act on): active_only freezes passive
+                # parties' encoders, the AFSVRG-VP analogue (deep_vfl's
+                # freeze_passive).
+                trainq = np.asarray(
+                    [1.0 if (not active_only or p < layout.m) else 0.0
+                     for p in range(layout.q)], np.float32)
+            with tracing.span("vfb2.engine.place"):
+                # ends when the copies have arrived, not when they are
+                # queued, so the span times the transfer
+                self.xs = self.place(xs)
+                self.y = self.place(np.asarray(y, np.float32), party=False)
+                self.maskq = self.place(maskq)
+                self.trainq = self.place(trainq)
+                jax.block_until_ready((self.xs, self.y, self.maskq,
+                                       self.trainq))
         self.dp = int(self.xs.shape[2])
-        self.y = self.place(np.asarray(y, np.float32), party=False)
-        self.maskq = self.place(pack_mask(layout, active_only))
-        # (q,) per-party trainability flag for the deep epochs' non-feature
-        # parameters (b1/w2 have no coordinate rows for maskq to act on):
-        # active_only freezes passive parties' encoders, the AFSVRG-VP
-        # analogue (deep_vfl's freeze_passive).
-        self.trainq = self.place(np.asarray(
-            [1.0 if (not active_only or p < layout.m) else 0.0
-             for p in range(layout.q)], np.float32))
         pm = self.pmesh
         self._slots = pm.slots if pm is not None else layout.q
         self._pps = pm.parties_per_slot if pm is not None else 1
@@ -485,9 +530,11 @@ class FusedEngine:
         """
         tm = jax.tree_util.tree_map
         slots, pps, ddp = self._slots, self._pps, self._ddp
-        fn = party_fn
+        # what the party function stages outside the helpers' own scopes
+        # (ϑ, the regulariser, the masked update) is ``vfb2.party``
+        fn = _scoped("vfb2.party")(party_fn)
         if pps > 1:
-            fn = jax.vmap(party_fn, in_axes=(0, None), out_axes=0,
+            fn = jax.vmap(fn, in_axes=(0, None), out_axes=0,
                           axis_name=self.pmesh.party_axis)
         if self._use_shard_map:
             def island(local, shared):
@@ -547,6 +594,7 @@ class FusedEngine:
     def _route_kernel(self, rows: int) -> bool:
         return self._kernel and rows <= self.cfg.kernel_max_rows
 
+    @_scoped("vfb2.contract")
     def _fwd(self, xb, wcols):
         """(B, dp) @ (dp, M) -> (B, M) forward partial products."""
         if self._route_kernel(xb.shape[0]):
@@ -556,6 +604,7 @@ class FusedEngine:
             return z
         return xb @ wcols
 
+    @_scoped("vfb2.contract")
     def _bwd(self, xb, thcols, denom: int):
         """(dp, M) BUM data gradients XᵀΘ/denom (reg term added by caller).
 
@@ -569,6 +618,7 @@ class FusedEngine:
             return g
         return xb.T @ thcols / denom
 
+    @_scoped("vfb2.contract")
     def _bwd_doms(self, xb, theta, m: int, denom: int):
         """(dp, m) per-dominator BUM data gradients from the concatenated
         (m·B, dp) minibatch block: column j = X_{b_j}ᵀϑ_j / denom.
@@ -586,6 +636,7 @@ class FusedEngine:
         return jnp.einsum("jbd,jb->dj", xb.reshape(m, b, xb.shape[1]),
                           theta.reshape(m, b)) / denom
 
+    @_scoped("vfb2.contract")
     def _bwd_doms_wide(self, rows, cots, m: int, denom: int):
         """(D, m, K) per-dominator Jacobian-transpose blocks from the
         concatenated (m·B, D) row block and (m·B, K) vector cotangents:
@@ -602,6 +653,7 @@ class FusedEngine:
             return g.reshape(rows.shape[1], m, cots.shape[1])
         return _seg_contract(rows, cots, m) / denom
 
+    @_scoped("vfb2.contract")
     def _pipe(self, xb_bwd, xb_fwd, wcols, thcols, denom: int):
         """The pipelined step's single contraction: the BUM application of
         round t (``xb_bwd`` against Θ = ``thcols``) and the forward partial
@@ -619,6 +671,7 @@ class FusedEngine:
                 block_b=self.cfg.block_b, block_d=self.cfg.block_d)
         return xb_fwd @ wcols, xb_bwd.T @ thcols / denom
 
+    @_scoped("vfb2.contract")
     def _pipe_doms_wide(self, xb_bwd, xb_fwd, wcols, cots, m: int,
                         denom: int):
         """Pipelined per-dominator *vector* contraction: backward(t)'s m
@@ -635,6 +688,7 @@ class FusedEngine:
             return z, g.reshape(xb_bwd.shape[1], m, cots.shape[1])
         return xb_fwd @ wcols, _seg_contract(xb_bwd, cots, m) / denom
 
+    @_scoped("vfb2.contract")
     def _pipe_doms(self, xb_bwd, xb_fwd, wp, theta, m: int, denom: int):
         """Pipelined multi-dominator contraction: backward(t)'s m
         per-dominator columns (block-diagonal Θ, as in :meth:`_bwd_doms`)
@@ -650,6 +704,7 @@ class FusedEngine:
                         theta.reshape(m, b)) / denom
         return xb_fwd @ wp, gg
 
+    @_scoped("vfb2.aggregate")
     def _agg(self, z, kt):
         """Masked secure aggregation of partials over the party axis.
 
@@ -677,6 +732,7 @@ class FusedEngine:
                            schedule_faithful=cfg.schedule_faithful,
                            q=self.q)
 
+    @_scoped("vfb2.aggregate")
     def _agg_members(self, z, kt, alive):
         """Survivor-aware masked aggregation (the faulted epochs' Alg. 1).
 
@@ -740,16 +796,22 @@ class FusedEngine:
         return jax.random.fold_in(
             kt, 0xda7a + jax.lax.axis_index(self._data_axis))
 
+    @_scoped("vfb2.sample")
     def _keys(self, key, steps: int):
         """Per-step mask keys, derived off the sampling key's stream."""
         return jax.random.split(jax.random.fold_in(key, 0x5ec), steps)
+
+    @_scoped("vfb2.gather")
+    def _rows(self, xp, ib):
+        """The minibatch's rows of this party's feature block."""
+        return xp[ib]
 
     def _epoch(self, name, builder):
         """Build-and-cache the jitted epoch function for this instance."""
         if name not in self._jitted:
             self._building = name
             try:
-                self._jitted[name] = F32Program(builder())
+                self._jitted[name] = F32Program(builder(), name)
             finally:
                 self._building = None
         return self._jitted[name]
@@ -785,7 +847,7 @@ class FusedEngine:
                     # (denominated by the FULL batch) are psum'd back
                     # over the data axis — identity without one
                     ibs = self._dslice(ib)
-                    xb = xp[ibs]
+                    xb = self._rows(xp, ibs)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg(z, self._dkey(kt))
                     theta = prob.theta(agg, y[ibs])
@@ -802,7 +864,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"),
                                donate_argnames=self._donate("wq"))
             def epoch(xs, wq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -849,7 +911,7 @@ class FusedEngine:
                 def body(wp, inp):
                     ib, kt = inp
                     ibs = self._dslice(ib)
-                    xb = xp[ibs]
+                    xb = self._rows(xp, ibs)
                     z = self._fwd(xb, jnp.stack([wp, wsp], axis=1))  # (B, 2)
                     agg = self._agg(z, self._dkey(kt))
                     th1 = prob.theta(agg[:, 0], y[ibs])
@@ -868,7 +930,7 @@ class FusedEngine:
 
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, wq, wq_snap, muq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, wq_snap, muq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -919,7 +981,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, tab, avgp = carry
                     ib, kt = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg(z, kt)
                     th_new = prob.theta(agg, y[ib])
@@ -945,7 +1007,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "tabq",
                                                             "avgq"))
             def epoch(xs, wq, tabq, avgq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, tabq, avgq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -974,7 +1036,7 @@ class FusedEngine:
                 def body(wp, inp):
                     ibf, kt = inp                 # ibf: (m·B,) concatenated
                     b = ibf.shape[0] // m
-                    xb = xp[ibf]
+                    xb = self._rows(xp, ibf)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg(z, kt)        # all m partials, one pass
                     theta = prob.theta(agg, y[ibf])
@@ -990,7 +1052,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"),
                                donate_argnames=self._donate("wq"))
             def epoch(xs, wq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -1016,7 +1078,7 @@ class FusedEngine:
                 def body(wp, inp):
                     ibf, kt = inp
                     b = ibf.shape[0] // m
-                    xb = xp[ibf]
+                    xb = self._rows(xp, ibf)
                     z = self._fwd(xb, jnp.stack([wp, wsp], axis=1))
                     agg = self._agg(z, kt)
                     th1 = prob.theta(agg[:, 0], y[ibf])
@@ -1034,7 +1096,7 @@ class FusedEngine:
 
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, wq, wq_snap, muq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, wq_snap, muq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -1062,7 +1124,7 @@ class FusedEngine:
                     wp, tab, avgp = carry
                     ibf, kt = inp
                     b = ibf.shape[0] // m
-                    xb = xp[ibf]
+                    xb = self._rows(xp, ibf)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg(z, kt)
                     th_new = prob.theta(agg, y[ibf])
@@ -1086,7 +1148,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "tabq",
                                                             "avgq"))
             def epoch(xs, wq, tabq, avgq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, tabq, avgq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -1117,7 +1179,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, buf, t = carry
                     ib, kt = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg(z, kt)
                     theta = prob.theta(agg, y[ib])
@@ -1143,7 +1205,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "bufq"))
             def epoch(xs, wq, bufq, delays_q, maskq, y, lr, key, t0, batch,
                       steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, bufq, delays_q, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
 
@@ -1176,7 +1238,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, buf, t = carry
                     ib, kt, fl, bl, ex = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg_members(z, kt, fl)
                     theta = prob.theta(agg, y[ib])
@@ -1203,7 +1265,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "bufq"))
             def epoch(xs, wq, bufq, delays_q, fwdq, bwdq, extraq, maskq,
                       y, lr, key, t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, bufq, delays_q, fwdq, bwdq, extraq,
                                maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
@@ -1235,7 +1297,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, buf, t = carry
                     ib, kt, fl, bl, ex = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, jnp.stack([wp, wsp], axis=1))
                     agg = self._agg_members(z, kt, fl)
                     th1 = prob.theta(agg[:, 0], y[ib])
@@ -1264,7 +1326,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, wq, wq_snap, muq, bufq, delays_q, fwdq, bwdq,
                       extraq, maskq, y, lr, key, t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, wq_snap, muq, bufq, delays_q, fwdq,
                                bwdq, extraq, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
@@ -1298,7 +1360,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, tab, avgp, buf, t = carry
                     ib, kt, fl, bl, ex = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg_members(z, kt, fl)
                     th_new = prob.theta(agg, y[ib])
@@ -1331,7 +1393,7 @@ class FusedEngine:
                                    "wq", "tabq", "avgq", "bufq"))
             def epoch(xs, wq, tabq, avgq, bufq, delays_q, fwdq, bwdq,
                       extraq, maskq, y, lr, key, t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, tabq, avgq, bufq, delays_q, fwdq,
                                bwdq, extraq, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
@@ -1364,6 +1426,7 @@ class FusedEngine:
     # is), which is exactly the declassification ``analysis.taint``
     # grants ``is_finite`` — see that module's docstring.
 
+    @_scoped("vfb2.guard")
     def _guard_fwd(self, z, cc, fl, guard: bool):
         """Corrupt, verdict, sanitize: the guarded epochs' shared
         forward-side step.  Returns (shippable partial, healthy flag,
@@ -1396,7 +1459,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, buf, t = carry
                     ib, kt, fl, bl, ex, cc = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     zs, zc, healthy, live = self._guard_fwd(z, cc, fl,
                                                             guard)
@@ -1427,7 +1490,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "bufq"))
             def epoch(xs, wq, bufq, delays_q, fwdq, bwdq, extraq,
                       corruptq, maskq, y, lr, key, t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, bufq, delays_q, fwdq, bwdq, extraq,
                                corruptq, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
@@ -1459,7 +1522,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, buf, t = carry
                     ib, kt, fl, bl, ex, cc = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, jnp.stack([wp, wsp], axis=1))
                     zs, zc, healthy, live = self._guard_fwd(z, cc, fl,
                                                             guard)
@@ -1493,7 +1556,7 @@ class FusedEngine:
             def epoch(xs, wq, wq_snap, muq, bufq, delays_q, fwdq, bwdq,
                       extraq, corruptq, maskq, y, lr, key, t0, batch,
                       steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, wq_snap, muq, bufq, delays_q, fwdq,
                                bwdq, extraq, corruptq, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
@@ -1524,7 +1587,7 @@ class FusedEngine:
                 def body(carry, inp):
                     wp, tab, avgp, buf, t = carry
                     ib, kt, fl, bl, ex, cc = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     zs, zc, healthy, live = self._guard_fwd(z, cc, fl,
                                                             guard)
@@ -1562,7 +1625,7 @@ class FusedEngine:
             def epoch(xs, wq, tabq, avgq, bufq, delays_q, fwdq, bwdq,
                       extraq, corruptq, maskq, y, lr, key, t0, batch,
                       steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, tabq, avgq, bufq, delays_q, fwdq,
                                bwdq, extraq, corruptq, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
@@ -1598,7 +1661,7 @@ class FusedEngine:
                     wp, buf, t = carry
                     ibf, kt = inp
                     b = ibf.shape[0] // m
-                    xb = xp[ibf]
+                    xb = self._rows(xp, ibf)
                     z = self._fwd(xb, wp[:, None])[:, 0]
                     agg = self._agg(z, kt)
                     theta = prob.theta(agg, y[ibf])
@@ -1625,7 +1688,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "bufq"))
             def epoch(xs, wq, bufq, delays_qm, maskq, y, lr, key, t0,
                       batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, bufq, delays_qm, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
 
@@ -1661,7 +1724,7 @@ class FusedEngine:
                 xp, wp, maskp = local
                 y, lr, idx, mkeys = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]      # prologue
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -1669,7 +1732,7 @@ class FusedEngine:
                     wp, xb, ib, agg = carry
                     ib_next, kt = inp
                     theta = prob.theta(agg, y[ib])
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     z_next, g = self._pipe(xb, xb_next, wp[:, None],
                                            theta[:, None], ib.shape[0])
                     agg_next = self._agg(z_next[:, 0], kt)
@@ -1689,7 +1752,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"),
                                donate_argnames=self._donate("wq"))
             def epoch(xs, wq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -1710,7 +1773,7 @@ class FusedEngine:
                 xp, wp, wsp, mup, maskp = local
                 y, lr, idx, mkeys = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, jnp.stack([wp, wsp], axis=1))  # (B, 2)
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -1725,7 +1788,7 @@ class FusedEngine:
                     ib_next, kt = inp
                     th1 = prob.theta(agg[:, 0], y[ib])
                     th0 = prob.theta(agg[:, 1], y[ib])
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     z_next, gg = self._pipe(
                         xb, xb_next, jnp.stack([wp, wsp], axis=1),
                         jnp.stack([th1, th0], axis=1), ib.shape[0])
@@ -1746,7 +1809,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, wq, wq_snap, muq, maskq, y, lr, key, batch,
                       steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, wq_snap, muq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -1769,7 +1832,7 @@ class FusedEngine:
                 y, lr, idx, mkeys = shared
                 n = y.shape[0]
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -1786,7 +1849,7 @@ class FusedEngine:
                     ib_next, kt = inp
                     th_new = prob.theta(agg, y[ib])
                     dth = (th_new - tab[ib])[:, None]
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     z_next, raw = self._pipe(xb, xb_next, wp[:, None],
                                              dth, 1)
                     agg_next = self._agg(z_next[:, 0], kt)
@@ -1809,7 +1872,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "tabq",
                                                             "avgq"))
             def epoch(xs, wq, tabq, avgq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, tabq, avgq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -1832,7 +1895,7 @@ class FusedEngine:
                 xp, wp, buf, delay, maskp = local
                 y, lr, idx, mkeys, t0 = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -1849,7 +1912,7 @@ class FusedEngine:
                     wp, buf, t, xb, ib, agg = carry
                     ib_next, kt = inp
                     theta = prob.theta(agg, y[ib])
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     z_next, g = self._pipe(xb, xb_next, wp[:, None],
                                            theta[:, None], ib.shape[0])
                     agg_next = self._agg(z_next[:, 0], kt)
@@ -1873,7 +1936,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "bufq"))
             def epoch(xs, wq, bufq, delays_q, maskq, y, lr, key, t0, batch,
                       steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 return mapped((xs, wq, bufq, delays_q, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
 
@@ -1898,7 +1961,7 @@ class FusedEngine:
                 xp, wp, maskp = local
                 y, lr, idx, mkeys = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -1907,7 +1970,7 @@ class FusedEngine:
                     ibf_next, kt = inp
                     b = ibf.shape[0] // m
                     theta = prob.theta(agg, y[ibf])
-                    xb_next = xp[ibf_next]
+                    xb_next = self._rows(xp, ibf_next)
                     z_next, gg = self._pipe_doms(xb, xb_next, wp, theta,
                                                  m, b)
                     agg_next = self._agg(z_next, kt)
@@ -1928,7 +1991,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"),
                                donate_argnames=self._donate("wq"))
             def epoch(xs, wq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -1949,7 +2012,7 @@ class FusedEngine:
                 xp, wp, wsp, mup, maskp = local
                 y, lr, idx, mkeys = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, jnp.stack([wp, wsp], axis=1))
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -1965,7 +2028,7 @@ class FusedEngine:
                     b = ibf.shape[0] // m
                     th1 = prob.theta(agg[:, 0], y[ibf])
                     th0 = prob.theta(agg[:, 1], y[ibf])
-                    xb_next = xp[ibf_next]
+                    xb_next = self._rows(xp, ibf_next)
                     z_next, gg = self._pipe(
                         xb, xb_next, jnp.stack([wp, wsp], axis=1),
                         jnp.stack([th1, th0], axis=1), b)
@@ -1986,7 +2049,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, wq, wq_snap, muq, maskq, y, lr, key, batch,
                       steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, wq_snap, muq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -2009,7 +2072,7 @@ class FusedEngine:
                 y, lr, idx, mkeys = shared
                 n = y.shape[0]
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -2028,7 +2091,7 @@ class FusedEngine:
                     ibf_next, kt = inp
                     th_new = prob.theta(agg, y[ibf])
                     dth = th_new - tab[ibf]
-                    xb_next = xp[ibf_next]
+                    xb_next = self._rows(xp, ibf_next)
                     z_next, raws = self._pipe_doms(xb, xb_next, wp, dth,
                                                    m, 1)
                     agg_next = self._agg(z_next, kt)
@@ -2051,7 +2114,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "tabq",
                                                             "avgq"))
             def epoch(xs, wq, tabq, avgq, maskq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, tabq, avgq, maskq),
                               (y, lr, idx, self._keys(key, steps)))
 
@@ -2075,7 +2138,7 @@ class FusedEngine:
                 xp, wp, buf, delay, maskp = local    # delay: (m,)
                 y, lr, idx, mkeys, t0 = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
                 agg0 = self._agg(z0, mkeys[0])
 
@@ -2094,7 +2157,7 @@ class FusedEngine:
                     ibf_next, kt = inp
                     b = ibf.shape[0] // m
                     theta = prob.theta(agg, y[ibf])
-                    xb_next = xp[ibf_next]
+                    xb_next = self._rows(xp, ibf_next)
                     z_next, gg = self._pipe_doms(xb, xb_next, wp, theta,
                                                  m, b)
                     agg_next = self._agg(z_next, kt)
@@ -2119,7 +2182,7 @@ class FusedEngine:
                                donate_argnames=self._donate("wq", "bufq"))
             def epoch(xs, wq, bufq, delays_qm, maskq, y, lr, key, t0,
                       batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 return mapped((xs, wq, bufq, delays_qm, maskq),
                               (y, lr, idx, self._keys(key, steps), t0))
 
@@ -2209,7 +2272,7 @@ class FusedEngine:
                     w1, b1, w2, head = carry
                     ib, kt = inp
                     g_w1, g_b1, g_w2, g_head = self._deep_grads(
-                        xp[ib], y[ib], w1, b1, w2, head, kt, mdom)
+                        self._rows(xp, ib), y[ib], w1, b1, w2, head, kt, mdom)
                     w1 = w1 - lr * maskp[:, None] * g_w1
                     b1 = b1 - lr * trainp * g_b1
                     w2 = w2 - lr * trainp * g_w2
@@ -2225,7 +2288,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"),
                                donate_argnames=self._donate("pq"))
             def epoch(xs, pq, maskq, trainq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], mdom * batch, steps)
+                idx = _sample_indices(key, y.shape[0], mdom * batch, steps)
                 w1q, b1q, w2q, headq = pq
                 return mapped((xs, w1q, b1q, w2q, headq, maskq, trainq),
                               (y, lr, idx, self._keys(key, steps)))
@@ -2291,7 +2354,7 @@ class FusedEngine:
                 def body(carry, inp):
                     w1, b1, w2, head = carry
                     ib, kt = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     yb = y[ib]
                     bsz = yb.shape[0] // mdom
                     uu = self._fwd(xb, jnp.concatenate([w1, w1s], axis=1))
@@ -2340,7 +2403,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, pq, pq_snap, muq, maskq, trainq, y, lr, key,
                       batch, steps):
-                idx = _batch_indices(key, y.shape[0], mdom * batch, steps)
+                idx = _sample_indices(key, y.shape[0], mdom * batch, steps)
                 w1q, b1q, w2q, headq = pq
                 w1s, b1s, w2s, headsq = pq_snap
                 return mapped((xs, w1q, b1q, w2q, headq, w1s, b1s, w2s,
@@ -2405,7 +2468,7 @@ class FusedEngine:
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
                     ib, kt = inp
                     g_w1, g_b1, g_w2, g_head = self._deep_grads(
-                        xp[ib], y[ib], w1, b1, w2, head, kt)
+                        self._rows(xp, ib), y[ib], w1, b1, w2, head, kt)
                     slot = t % (tau + 1)
                     bw1 = jax.lax.dynamic_update_index_in_dim(bw1, g_w1,
                                                               slot, 0)
@@ -2438,7 +2501,7 @@ class FusedEngine:
                                donate_argnames=self._donate("pq", "bufq"))
             def epoch(xs, pq, bufq, delays_q, maskq, trainq, y, lr, key,
                       t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 w1q, b1q, w2q, headq = pq
                 bw1q, bb1q, bw2q = bufq
                 return mapped((xs, w1q, b1q, w2q, headq, bw1q, bb1q, bw2q,
@@ -2492,7 +2555,7 @@ class FusedEngine:
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
                     ib, kt, fl, bl, ex = inp
                     g_w1, g_b1, g_w2, g_head = self._deep_fault_grads(
-                        xp[ib], y[ib], w1, b1, w2, head, kt, fl)
+                        self._rows(xp, ib), y[ib], w1, b1, w2, head, kt, fl)
                     slot = t % (tau + 1)
                     bw1 = jnp.where(
                         bl > 0,
@@ -2531,7 +2594,7 @@ class FusedEngine:
                                donate_argnames=self._donate("pq", "bufq"))
             def epoch(xs, pq, bufq, delays_q, fwdq, bwdq, extraq, maskq,
                       trainq, y, lr, key, t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 w1q, b1q, w2q, headq = pq
                 bw1q, bb1q, bw2q = bufq
                 return mapped((xs, w1q, b1q, w2q, headq, bw1q, bb1q, bw2q,
@@ -2569,7 +2632,7 @@ class FusedEngine:
                 def body(carry, inp):
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
                     ib, kt, fl, bl, ex = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     yb = y[ib]
                     bsz = yb.shape[0]
                     uu = self._fwd(xb, jnp.concatenate([w1, w1s], axis=1))
@@ -2639,7 +2702,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, pq, pq_snap, muq, bufq, delays_q, fwdq, bwdq,
                       extraq, maskq, trainq, y, lr, key, t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 w1q, b1q, w2q, headq = pq
                 w1s, b1s, w2s, headsq = pq_snap
                 bw1q, bb1q, bw2q = bufq
@@ -2678,7 +2741,7 @@ class FusedEngine:
                 def body(carry, inp):
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
                     ib, kt, fl, bl, ex, cc = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     yb = y[ib]
                     bsz = yb.shape[0]
                     h = jnp.tanh(self._fwd(xb, w1) + b1)
@@ -2739,7 +2802,7 @@ class FusedEngine:
             def epoch(xs, pq, bufq, delays_q, fwdq, bwdq, extraq,
                       corruptq, maskq, trainq, y, lr, key, t0, batch,
                       steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 w1q, b1q, w2q, headq = pq
                 bw1q, bb1q, bw2q = bufq
                 return mapped((xs, w1q, b1q, w2q, headq, bw1q, bb1q, bw2q,
@@ -2778,7 +2841,7 @@ class FusedEngine:
                 def body(carry, inp):
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
                     ib, kt, fl, bl, ex, cc = inp
-                    xb = xp[ib]
+                    xb = self._rows(xp, ib)
                     yb = y[ib]
                     bsz = yb.shape[0]
                     uu = self._fwd(xb, jnp.concatenate([w1, w1s], axis=1))
@@ -2857,7 +2920,7 @@ class FusedEngine:
             def epoch(xs, pq, pq_snap, muq, bufq, delays_q, fwdq, bwdq,
                       extraq, corruptq, maskq, trainq, y, lr, key, t0,
                       batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 w1q, b1q, w2q, headq = pq
                 w1s, b1s, w2s, headsq = pq_snap
                 bw1q, bb1q, bw2q = bufq
@@ -2929,7 +2992,7 @@ class FusedEngine:
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
                     ibf, kt = inp
                     gw1, gb1, gw2, gh = self._deep_dom_grads(
-                        xp[ibf], y[ibf], w1, b1, w2, head, kt, m)
+                        self._rows(xp, ibf), y[ibf], w1, b1, w2, head, kt, m)
                     (bw1, bb1, bw2), (s_w1, s_b1, s_w2) = \
                         self._ring_put_take_multi(
                             (bw1, bb1, bw2), (gw1, gb1, gw2), t, delay, tau)
@@ -2951,7 +3014,7 @@ class FusedEngine:
                                donate_argnames=self._donate("pq", "bufq"))
             def epoch(xs, pq, bufq, delays_qm, maskq, trainq, y, lr, key,
                       t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 w1q, b1q, w2q, headq = pq
                 bw1q, bb1q, bw2q = bufq
                 return mapped((xs, w1q, b1q, w2q, headq, bw1q, bb1q, bw2q,
@@ -3008,7 +3071,7 @@ class FusedEngine:
                 xp, w1, b1, w2, head, maskp, trainp = local
                 y, lr, idx, mkeys = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 u0 = self._fwd(xb0, w1)               # prologue launch
                 h0 = jnp.tanh(u0 + b1)
                 agg0 = self._agg(h0 @ w2, mkeys[0])
@@ -3024,7 +3087,7 @@ class FusedEngine:
                     ib_next, kt = inp
                     du, g_b1, g_w2, g_head = self._deep_pipe_tail(
                         h, agg, y[ib], b1, w2, head, mdom)
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     u_next, g1 = self._pipe(xb, xb_next, w1, du, 1)
                     g_w1 = g1 + mdom * prob.lam * prob.reg_grad(w1)
                     h_next = jnp.tanh(u_next + b1)    # pre-update params
@@ -3048,7 +3111,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"),
                                donate_argnames=self._donate("pq"))
             def epoch(xs, pq, maskq, trainq, y, lr, key, batch, steps):
-                idx = _batch_indices(key, y.shape[0], mdom * batch, steps)
+                idx = _sample_indices(key, y.shape[0], mdom * batch, steps)
                 w1q, b1q, w2q, headq = pq
                 return mapped((xs, w1q, b1q, w2q, headq, maskq, trainq),
                               (y, lr, idx, self._keys(key, steps)))
@@ -3139,7 +3202,7 @@ class FusedEngine:
                             head - lr * v_head)
 
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 wpair = jnp.concatenate([w1, w1s], axis=1)
                 h0, hs0, zz0 = fwd_pair(self._fwd(xb0, wpair), mkeys[0])
 
@@ -3148,7 +3211,7 @@ class FusedEngine:
                     ib_next, kt = inp
                     du1, du0, v_b1, v_w2, v_head = tail(h, hs, zz, y[ib],
                                                         b1, w2, head)
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     uu_next, duu = self._pipe(
                         xb, xb_next, jnp.concatenate([w1, w1s], axis=1),
                         jnp.concatenate([du1, du0], axis=1), 1)
@@ -3177,7 +3240,7 @@ class FusedEngine:
             @functools.partial(jax.jit, static_argnames=("batch", "steps"))
             def epoch(xs, pq, pq_snap, muq, maskq, trainq, y, lr, key,
                       batch, steps):
-                idx = _batch_indices(key, y.shape[0], mdom * batch, steps)
+                idx = _sample_indices(key, y.shape[0], mdom * batch, steps)
                 w1q, b1q, w2q, headq = pq
                 w1s, b1s, w2s, headsq = pq_snap
                 return mapped((xs, w1q, b1q, w2q, headq, w1s, b1s, w2s,
@@ -3236,7 +3299,7 @@ class FusedEngine:
                  trainp) = local
                 y, lr, idx, mkeys, t0 = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 h0 = jnp.tanh(self._fwd(xb0, w1) + b1)
                 agg0 = self._agg(h0 @ w2, mkeys[0])
 
@@ -3263,7 +3326,7 @@ class FusedEngine:
                     ib_next, kt = inp
                     du, g_b1, g_w2, g_head = self._deep_pipe_tail(
                         h, agg, y[ib], b1, w2, head, 1)
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     u_next, g1 = self._pipe(xb, xb_next, w1, du, 1)
                     g_w1 = g1 + prob.lam * prob.reg_grad(w1)
                     h_next = jnp.tanh(u_next + b1)
@@ -3293,7 +3356,7 @@ class FusedEngine:
                                donate_argnames=self._donate("pq", "bufq"))
             def epoch(xs, pq, bufq, delays_q, maskq, trainq, y, lr, key,
                       t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], batch, steps)
+                idx = _sample_indices(key, y.shape[0], batch, steps)
                 w1q, b1q, w2q, headq = pq
                 bw1q, bb1q, bw2q = bufq
                 return mapped((xs, w1q, b1q, w2q, headq, bw1q, bb1q, bw2q,
@@ -3330,7 +3393,7 @@ class FusedEngine:
                  trainp) = local                      # delay: (m,)
                 y, lr, idx, mkeys, t0 = shared
                 ib0 = idx[0]
-                xb0 = xp[ib0]
+                xb0 = self._rows(xp, ib0)
                 h0 = jnp.tanh(self._fwd(xb0, w1) + b1)
                 agg0 = self._agg(h0 @ w2, mkeys[0])
 
@@ -3349,7 +3412,7 @@ class FusedEngine:
                     ib_next, kt = inp
                     du, gb1, gw2, gh = self._deep_pipe_dom_tail(
                         h, agg, y[ib], b1, w2, head, m)
-                    xb_next = xp[ib_next]
+                    xb_next = self._rows(xp, ib_next)
                     # Mθ = m·hidden block-diagonal du beside the Mw =
                     # hidden forward — the split-batch form's vector-valued
                     # per-side column counts
@@ -3384,7 +3447,7 @@ class FusedEngine:
                                donate_argnames=self._donate("pq", "bufq"))
             def epoch(xs, pq, bufq, delays_qm, maskq, trainq, y, lr, key,
                       t0, batch, steps):
-                idx = _batch_indices(key, y.shape[0], m * batch, steps)
+                idx = _sample_indices(key, y.shape[0], m * batch, steps)
                 w1q, b1q, w2q, headq = pq
                 bw1q, bb1q, bw2q = bufq
                 return mapped((xs, w1q, b1q, w2q, headq, bw1q, bb1q, bw2q,
@@ -3519,6 +3582,17 @@ class FusedEngine:
     def unpack_deep(self, pq):
         return unpack_deep_params(pq, self.layout)
 
+    @staticmethod
+    def _read_objective(enqueue) -> float:
+        """``float(enqueue())`` as the ``vfb2.objective`` span: the eager
+        ops' dispatch (``.enqueue``), then the wait for the device and the
+        copy of the scalar to the host (``.fetch``)."""
+        with tracing.span("vfb2.objective"):
+            with tracing.span("vfb2.objective.enqueue"):
+                value = enqueue()
+            with tracing.span("vfb2.objective.fetch"):
+                return float(value)
+
     def deep_objective(self, pq) -> float:
         """Full deep objective (one device sync; per-epoch telemetry).
 
@@ -3527,14 +3601,18 @@ class FusedEngine:
         replicated head is counted once."""
         prob = self.problem
         w1q, b1q, w2q, headq = pq
-        with jax.default_matmul_precision("highest"):   # as F32Program
-            h = jnp.tanh(jnp.einsum("qnd,qdh->qnh", self.xs, w1q)
-                         + b1q[:, None, :])
-            z = jnp.einsum("qnh,qhr->nr", h, w2q)
-            logit = z @ headq[0]
-        regv = (jnp.sum(prob.reg(w1q)) + jnp.sum(prob.reg(b1q))
-                + jnp.sum(prob.reg(w2q)) + jnp.sum(prob.reg(headq[0])))
-        return float(jnp.mean(prob.loss(logit, self.y)) + prob.lam * regv)
+
+        def enqueue():
+            with jax.default_matmul_precision("highest"):  # as F32Program
+                h = jnp.tanh(jnp.einsum("qnd,qdh->qnh", self.xs, w1q)
+                             + b1q[:, None, :])
+                z = jnp.einsum("qnh,qhr->nr", h, w2q)
+                logit = z @ headq[0]
+            regv = (jnp.sum(prob.reg(w1q)) + jnp.sum(prob.reg(b1q))
+                    + jnp.sum(prob.reg(w2q)) + jnp.sum(prob.reg(headq[0])))
+            return jnp.mean(prob.loss(logit, self.y)) + prob.lam * regv
+
+        return self._read_objective(enqueue)
 
     def objective(self, wq) -> float:
         """Full objective (one device sync; for per-epoch telemetry).
@@ -3542,7 +3620,11 @@ class FusedEngine:
         The padded coordinates are zero and every shipped regularizer maps
         0 → 0, so summing ``reg`` over the padded stack is exact."""
         prob = self.problem
-        with jax.default_matmul_precision("highest"):   # as F32Program
-            agg = jnp.einsum("qnd,qd->n", self.xs, wq)
-        return float(jnp.mean(prob.loss(agg, self.y))
-                     + prob.lam * jnp.sum(prob.reg(wq)))
+
+        def enqueue():
+            with jax.default_matmul_precision("highest"):  # as F32Program
+                agg = jnp.einsum("qnd,qd->n", self.xs, wq)
+            return (jnp.mean(prob.loss(agg, self.y))
+                    + prob.lam * jnp.sum(prob.reg(wq)))
+
+        return self._read_objective(enqueue)

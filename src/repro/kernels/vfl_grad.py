@@ -363,6 +363,7 @@ def vfl_grad(xb, w, theta, lam=0.0, *, interpret: bool, block_b: int = 128,
         out_shape=[s[1] for s in sides],
         scratch_shapes=[s[2] for s in sides if s[2] is not None],
         interpret=interpret,
+        name=f"vfl_grad_{mode}",    # the kernel's name in a device trace
     )(*operands)
     if not fwd:
         z = None
