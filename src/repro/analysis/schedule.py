@@ -59,7 +59,7 @@ def _call_body(eqn):
     call."""
     if "num_carry" in eqn.params:
         return None
-    for key in ("jaxpr", "call_jaxpr"):
+    for key in ("jaxpr", "call_jaxpr", "call"):
         sub = eqn.params.get(key)
         inner = getattr(sub, "jaxpr", None)      # ClosedJaxpr only
         if (inner is not None and hasattr(inner, "eqns")

@@ -48,14 +48,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.walkers import CROSS_PARTY_PRIMS
 
-try:                               # jax >= 0.4.24 moved Literal around
-    Literal = jax_core.Literal
-except AttributeError:             # pragma: no cover - very old jax
-    from jax._src.core import Literal
+Literal = jax_core.Literal
 
 
 # A PRNG stream: (id of the random_bits eqn, frozenset of party-axis
@@ -285,7 +282,7 @@ class _Analyzer:
     @staticmethod
     def _call_jaxpr(eqn):
         """The ClosedJaxpr of a call-like primitive, if any."""
-        for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
+        for key in ("jaxpr", "call_jaxpr", "fun_jaxpr", "call"):
             v = eqn.params.get(key)
             if v is not None and hasattr(v, "jaxpr"):
                 return v

@@ -117,6 +117,24 @@ def scan_body_primitive_counts(jaxpr, name: str) -> List[int]:
     return counts
 
 
+def primitive_eqns(jaxpr, name: str) -> List:
+    """Every equation of primitive ``name`` in a (closed) jaxpr, nested
+    ones included (a kernel call inside its batching wrapper's ``call``
+    jaxpr, a scan body...), in program order."""
+    found: List = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == name:
+                found.append(eqn)
+            for v in eqn.params.values():
+                for s in sub_jaxprs(v):
+                    walk(s)
+
+    walk(_as_jaxpr(jaxpr))
+    return found
+
+
 def primitive_histogram(jaxpr) -> Dict[str, int]:
     """Full recursive primitive census of a (closed) jaxpr."""
     hist: Dict[str, int] = {}

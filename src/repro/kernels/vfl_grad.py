@@ -32,6 +32,25 @@ for g — so narrow operands (the deep-VFL encoder layers, rank-1 single-
 tile minibatches) write their outputs straight through with no dead VMEM
 scratch and no per-grid-step accumulator traffic in interpret mode.
 
+Parties in the block.  One party's call has 2-D blocks (the flat
+``shard_map`` mesh makes it so, one party per chip).  Under ``jax.vmap``
+over parties (the one-chip engine's emulated party axis, a packed
+PartyMesh slot) a batching rule (``jax.custom_batching.custom_vmap``)
+makes ONE call whose blocks carry Q of the G mapped parties — X
+(Q, Bb, Db), w (Q, Db, Mw), ϑ (Q, Bb, Mθ), z (Q, Bb, Mw), g (Q, Db, Mθ) —
+each contracted by its own dots in a static loop, with the grid still
+(nD, nB), or (G/Q, nD, nB) where Q < G.  Pallas's own rule would put the
+party axis in front of the grid instead: one sequential visit per party,
+which costs on the chip about what a whole call does.  Q is chosen from
+the shapes alone: all G where their double-buffered blocks and
+accumulators fit ``VMEM_BUDGET``, else the largest divisor of G that
+does.  A further vmap (the data axis, the slots around a packed slot)
+folds into the same group axis; an operand it leaves unbatched is
+broadcast.  Operands and outputs keep their shapes either way, (G, Bp, Dp)
+for X.  Each grouped call is counted at trace time, under the program
+being traced, as ``vfb2.kernel.grouped`` or, where the budget kept one
+party a visit, ``vfb2.kernel.per_party`` (``repro.tracing``).
+
 Shapes that do not divide the tile are zero-padded inside the wrapper and
 the outputs sliced back, so odd party widths (``PartyLayout.even`` with
 d % q != 0) work without caller-side ceremony.
@@ -66,13 +85,17 @@ or split-batch calls whose side column counts differ).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import custom_batching
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import tracing
 
 
 # Every in-kernel dot states its precision.  Given none, Mosaic's f32 dot
@@ -97,9 +120,10 @@ def _concrete_zero(lam) -> bool:
     return False
 
 
-def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
-                has_w: bool, use_lamw: bool, nsplit: int | None,
-                z_acc_used: bool, g_acc_used: bool):
+def _vfl_kernel(*refs, grid_axes: int, parties: int | None, denom: int,
+                block_b: int, fwd: bool, bwd: bool, has_w: bool,
+                use_lamw: bool, nsplit: int | None, z_acc_used: bool,
+                g_acc_used: bool):
     # Single-sided modes carry only their own operands/outputs (no HBM
     # traffic for a dead side); ref order follows the wrapper's specs.
     # ``has_w=False`` (backward with ``w=None``) additionally drops the
@@ -115,6 +139,10 @@ def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
     # tile) writes its output ref directly — no VMEM accumulator is
     # allocated and no per-grid-step accumulator traffic happens
     # (``z_acc_used``/``g_acc_used`` gate the scratch refs).
+    # ``parties``: None for one party's 2-D blocks; Q for grouped blocks
+    # whose leading axis holds Q parties, each contracted by its own dots
+    # in a static loop; the grid's last two axes are (nD, nB) either way,
+    # after a group axis where the parties span several blocks.
     it = iter(refs)
     x_ref = next(it)
     w_ref = next(it) if has_w else None
@@ -125,44 +153,67 @@ def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
     z_acc = next(it) if fwd and z_acc_used else None
     g_acc = next(it) if bwd and g_acc_used else None
 
-    di = pl.program_id(0)
-    bi = pl.program_id(1)
-    nb = pl.num_programs(1)
+    di = pl.program_id(grid_axes - 2)
+    bi = pl.program_id(grid_axes - 1)
+    nb = pl.num_programs(grid_axes - 1)
+    # one index prefix per party of the block: () or (p,)
+    prefixes = [()] if parties is None else [(p,) for p in range(parties)]
 
-    x = x_ref[...].astype(jnp.float32)                    # (Bb, Db)
-    w = None if w_ref is None else w_ref[...].astype(jnp.float32)  # (Db, Mw)
+    def whole(ix):
+        return ix + (Ellipsis,) if ix else Ellipsis
+
+    def each(fn):
+        def run():
+            for ix in prefixes:
+                fn(ix)
+        return run
+
+    def x_of(ix):
+        return x_ref[whole(ix)].astype(jnp.float32)       # (Bb, Db)
+
+    def w_of(ix):
+        return w_ref[whole(ix)].astype(jnp.float32)       # (Db, Mw)
+
+    def lam_w(ix, acc):
+        return acc + lam_ref[ix + (0, 0)] * w_of(ix) if use_lamw else acc
 
     if fwd:
-        def _z_work():
+        def _z_work(ix):
             # forward partials for this (feature, batch) tile: rank-k MXU
-            zt = jnp.dot(x, w, precision=_F32,
+            zt = jnp.dot(x_of(ix), w_of(ix), precision=_F32,
                          preferred_element_type=jnp.float32)
             if z_acc is None:
                 # nD == 1: one feature pass computes the full z — write
                 # the output block directly, no accumulator round-trip
-                z_ref[...] = zt
+                z_ref[whole(ix)] = zt
                 return
-            sl = pl.ds(bi * block_b, block_b)
+            acc = ix + (pl.ds(bi * block_b, block_b), slice(None))
 
             @pl.when(di == 0)
             def _z_init():
-                z_acc[sl, :] = zt
+                z_acc[acc] = zt
 
             @pl.when(di > 0)
             def _z_accum():
-                z_acc[sl, :] += zt
+                z_acc[acc] += zt
 
             # Written on every visit; the grid is sequential, so the final
             # feature pass (di == nD-1) is the last writer and the HBM
             # block holds the fully reduced z.  No out-of-kernel reduction
             # remains.  (Split-batch: backward-row tiles never write their
             # z block — the wrapper slices those rows away.)
-            z_ref[...] = z_acc[sl, :]
+            z_ref[whole(ix)] = z_acc[acc]
 
         if nsplit is None:
-            _z_work()
+            each(_z_work)()
         else:
-            pl.when(bi >= nsplit)(_z_work)
+            pl.when(bi >= nsplit)(each(_z_work))
+
+    def xt_theta(ix):
+        x = x_of(ix)
+        th = theta_ref[whole(ix)].astype(jnp.float32)     # (Bb, Mθ)
+        return jnp.dot(x.T, th, precision=_F32,
+                       preferred_element_type=jnp.float32)
 
     if bwd and g_acc is None:
         # A single backward row tile: XᵀΘ is complete after one visit, so
@@ -170,40 +221,220 @@ def _vfl_kernel(*refs, denom: int, block_b: int, fwd: bool, bwd: bool,
         # output block for feature tile di persists across the remaining
         # (forward-only) batch-tile visits — same sequential-grid
         # revisiting contract the z path relies on.
-        def _g_once():
-            th = theta_ref[...].astype(jnp.float32)       # (Bb, Mθ)
-            acc = jnp.dot(x.T, th, precision=_F32,
-                          preferred_element_type=jnp.float32) / denom
-            if use_lamw:
-                acc = acc + lam_ref[0, 0] * w
-            g_ref[...] = acc.astype(g_ref.dtype)
+        def _g_once(ix):
+            acc = lam_w(ix, xt_theta(ix) / denom)
+            g_ref[whole(ix)] = acc.astype(g_ref.dtype)
 
         if nsplit is None:
-            _g_once()
+            each(_g_once)()
         else:
-            pl.when(bi < nsplit)(_g_once)
+            pl.when(bi < nsplit)(each(_g_once))
     elif bwd:
         @pl.when(bi == 0)
         def _g_init():
             g_acc[...] = jnp.zeros_like(g_acc)
 
-        def _g_work():
-            th = theta_ref[...].astype(jnp.float32)       # (Bb, Mθ)
+        def _g_work(ix):
             # backward accumulate: XᵀΘ, f32 in VMEM
-            g_acc[...] += jnp.dot(x.T, th, precision=_F32,
-                                  preferred_element_type=jnp.float32)
+            g_acc[whole(ix)] += xt_theta(ix)
 
         if nsplit is None:
-            _g_work()
+            each(_g_work)()
         else:
-            pl.when(bi < nsplit)(_g_work)
+            pl.when(bi < nsplit)(each(_g_work))
 
-        @pl.when(bi == nb - 1)
-        def _g_finalize():
-            acc = g_acc[...] / denom
-            if use_lamw:
-                acc = acc + lam_ref[0, 0] * w
-            g_ref[...] = acc.astype(g_ref.dtype)
+        def _g_finalize(ix):
+            acc = lam_w(ix, g_acc[whole(ix)] / denom)
+            g_ref[whole(ix)] = acc.astype(g_ref.dtype)
+
+        pl.when(bi == nb - 1)(each(_g_finalize))
+
+
+#: VMEM a grouped call's blocks may take: half of the 16 MiB that Mosaic
+#: scopes for a kernel by default on a v5e, so its own scratch keeps room
+VMEM_BUDGET = 8 << 20
+
+
+def _vmem_bytes(shape) -> int:
+    """Bytes of an f32 VMEM buffer, its last two dims padded to the chip's
+    (8, 128) tile (an upper bound for a narrower dtype)."""
+    *lead, r, c = shape
+    return int(np.prod(lead)) * _round_up(r, 8) * _round_up(c, 128) * 4
+
+
+def parties_per_visit(q: int, party_bytes: int,
+                      budget: int = VMEM_BUDGET) -> int:
+    """Q, the parties one grid visit holds: the largest divisor of ``q``
+    whose Q parties' VMEM (``party_bytes`` each) fits ``budget`` — all q
+    where they fit, one where even two do not."""
+    return max(n for n in range(1, q + 1)
+               if q % n == 0 and (n == 1 or n * party_bytes <= budget))
+
+
+@dataclasses.dataclass(frozen=True)
+class _Call:
+    """The static shape of one kernel call, operands already padded."""
+    mode: str
+    interpret: bool
+    block_b: int
+    block_d: int
+    bp: int                 # padded rows
+    dp: int                 # padded columns
+    mw: int | None          # weight columns (None: no weight operand)
+    mth: int | None         # ϑ columns (None: forward mode)
+    nsplit: int | None      # backward-only row tiles of a split batch
+    denom: int
+    use_lamw: bool
+
+    @property
+    def fwd(self) -> bool:
+        return self.mode in ("fused", "forward")
+
+    @property
+    def bwd(self) -> bool:
+        return self.mode in ("fused", "backward")
+
+    @property
+    def grid(self) -> tuple:
+        return self.dp // self.block_d, self.bp // self.block_b
+
+    # Scratch elision (see kernel): the z accumulator exists only when the
+    # forward reduction spans >1 feature tile; the g accumulator only when
+    # the backward rows span >1 row tile (all rows without split, the
+    # backward block's tiles with it).
+    @property
+    def z_acc_used(self) -> bool:
+        return self.fwd and self.grid[0] > 1
+
+    @property
+    def g_acc_used(self) -> bool:
+        nb = self.grid[1] if self.nsplit is None else self.nsplit
+        return self.bwd and nb > 1
+
+    @property
+    def party_bytes(self) -> int:
+        """VMEM one party takes in a grid visit: its operand and output
+        blocks, double-buffered, and its accumulators."""
+        bb, bd = self.block_b, self.block_d
+        blocks = [(bb, bd)]
+        if self.mw is not None:
+            blocks.append((bd, self.mw))
+        if self.fwd:
+            blocks.append((bb, self.mw))
+        if self.bwd:
+            blocks += [(bb, self.mth), (bd, self.mth)]
+        scratch = [s for s, used in (((self.bp, self.mw), self.z_acc_used),
+                                     ((bd, self.mth), self.g_acc_used))
+                   if used]
+        return (2 * sum(map(_vmem_bytes, blocks))
+                + sum(map(_vmem_bytes, scratch)))
+
+
+def _pallas(c: _Call, operands, parties: int | None = None):
+    """The Mosaic call.  ``parties=None``: one party's 2-D operands, grid
+    (nD, nB).  ``parties=Q``: operands with a leading axis of G parties,
+    Q of them in each block, grid (nD, nB) when Q == G and (G/Q, nD, nB)
+    otherwise."""
+    nd, nb = c.grid
+    lead, grid = (), (nd, nb)
+    if parties is not None:
+        lead = (parties,)
+        groups = operands[0].shape[0] // parties
+        if groups > 1:
+            grid = (groups, nd, nb)
+    kernel = functools.partial(
+        _vfl_kernel, grid_axes=len(grid), parties=parties, denom=c.denom,
+        block_b=c.block_b, fwd=c.fwd, bwd=c.bwd, has_w=c.mw is not None,
+        use_lamw=c.use_lamw, nsplit=c.nsplit, z_acc_used=c.z_acc_used,
+        g_acc_used=c.g_acc_used)
+
+    def spec(shape, f, **kw):
+        """A block of ``shape`` per party at f(di, bi); grouped, behind the
+        block of parties the group index (0 without a group axis) picks."""
+        if parties is None:
+            return pl.BlockSpec(shape, f, **kw)
+        return pl.BlockSpec(
+            lead + shape,
+            lambda *ix: (ix[0] if len(ix) == 3 else 0,) + f(*ix[-2:]), **kw)
+
+    def out(rows, cols):
+        return jax.ShapeDtypeStruct(operands[0].shape[:-2] + (rows, cols),
+                                    jnp.float32)
+
+    bb, bd = c.block_b, c.block_d
+    # Mode-specific specs: a single-sided call neither streams the unused
+    # operand into VMEM nor DMAs a dead output back to HBM.  A dead side's
+    # column count is None, so each side's specs are built only under its
+    # own guard.
+    in_specs = [spec((bb, bd), lambda di, bi: (bi, di))]
+    if c.mw is not None:
+        in_specs.append(spec((bd, c.mw), lambda di, bi: (di, 0)))
+    if c.bwd:
+        in_specs.append(spec((bb, c.mth), lambda di, bi: (bi, 0)))
+    if c.use_lamw:
+        in_specs.append(spec((1, 1), lambda di, bi: (0, 0),
+                             memory_space=pltpu.SMEM))
+    sides = []
+    if c.fwd:
+        sides.append((spec((bb, c.mw), lambda di, bi: (bi, 0)),
+                      out(c.bp, c.mw),
+                      pltpu.VMEM(lead + (c.bp, c.mw), jnp.float32)
+                      if c.z_acc_used else None))
+    if c.bwd:
+        sides.append((spec((bd, c.mth), lambda di, bi: (di, 0)),
+                      out(c.dp, c.mth),
+                      pltpu.VMEM(lead + (bd, c.mth), jnp.float32)
+                      if c.g_acc_used else None))
+    return list(pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[s[0] for s in sides],
+        out_shape=[s[1] for s in sides],
+        scratch_shapes=[s[2] for s in sides if s[2] is not None],
+        interpret=c.interpret,
+        name=f"vfl_grad_{c.mode}",    # the kernel's name in a device trace
+    )(*operands))
+
+
+def _book(c: _Call, operands, n: int = 1):
+    """Count a grouped call at trace time: ``vfb2.kernel.grouped`` when
+    its blocks hold several parties (or its only one),
+    ``vfb2.kernel.per_party`` when the VMEM budget kept one party a visit.
+    Returns the call's Q."""
+    g = operands[0].shape[0]
+    qv = parties_per_visit(g, c.party_bytes)
+    kind = "per_party" if qv == 1 and g > 1 else "grouped"
+    tracing.count(f"vfb2.kernel.{kind}", n)
+    return qv
+
+
+def _invoke(c: _Call, operands, parties: int | None = None):
+    """The kernel call, ``parties`` as in :func:`_pallas`.  Under
+    ``jax.vmap`` (the engine's emulated party axis, a packed PartyMesh
+    slot) one party's call becomes ONE call whose blocks carry the mapped
+    parties, not the grid visit per party that Pallas's own batching rule
+    would add; a further vmap (the data axis, the slots around a packed
+    slot) folds its axis into the same group axis: A × G parties."""
+    @custom_batching.custom_vmap
+    def call(*ops):
+        return _pallas(c, ops, parties)
+
+    @call.def_vmap
+    def _batched(axis_size, in_batched, *ops):
+        # the mapped axis in front; an unbatched operand is broadcast to it
+        ops = [o if b else jnp.broadcast_to(o, (axis_size,) + o.shape)
+               for o, b in zip(ops, in_batched)]
+        if parties is not None:
+            g = operands[0].shape[0]
+            ops = [o.reshape((axis_size * g,) + o.shape[2:]) for o in ops]
+            _book(c, operands, -1)          # the call this one replaces
+        outs = _invoke(c, ops, _book(c, ops))
+        if parties is not None:
+            outs = [o.reshape((axis_size, g) + o.shape[1:]) for o in outs]
+        return outs, [True] * len(outs)
+
+    return call(*operands)
 
 
 def vfl_grad(xb, w, theta, lam=0.0, *, interpret: bool, block_b: int = 128,
@@ -315,56 +546,17 @@ def vfl_grad(xb, w, theta, lam=0.0, *, interpret: bool, block_b: int = 128,
         th2 = jnp.pad(th2, ((0, bp - split), (0, 0)))
     if w2 is not None and dp != d:
         w2 = jnp.pad(w2, ((0, dp - d), (0, 0)))
-    nb, nd = bp // block_b, dp // block_d
-
-    # Scratch elision (see kernel): the z accumulator exists only when the
-    # forward reduction spans >1 feature tile; the g accumulator only when
-    # the backward rows span >1 row tile (all rows without split, the
-    # backward block's tiles with it).
-    z_acc_used = fwd and nd > 1
-    g_acc_used = bwd and (nb if nsplit is None else nsplit) > 1
-
-    kernel = functools.partial(_vfl_kernel, denom=denom, block_b=block_b,
-                               fwd=fwd, bwd=bwd, has_w=has_w,
-                               use_lamw=use_lamw, nsplit=nsplit,
-                               z_acc_used=z_acc_used, g_acc_used=g_acc_used)
-    # Mode-specific specs: a single-sided call neither streams the unused
-    # operand into VMEM nor DMAs a dead output back to HBM.  A dead side's
-    # column count is None, so each side's specs are built only under its
-    # own guard.
-    in_specs = [pl.BlockSpec((block_b, block_d), lambda di, bi: (bi, di))]
+    call = _Call(mode=mode, interpret=interpret, block_b=block_b,
+                 block_d=block_d, bp=bp, dp=dp, mw=mw, mth=mth,
+                 nsplit=nsplit, denom=denom, use_lamw=use_lamw)
     operands = (xb,)
     if has_w:
-        in_specs.append(pl.BlockSpec((block_d, mw), lambda di, bi: (di, 0)))
         operands += (w2,)
     if bwd:
-        in_specs.append(pl.BlockSpec((block_b, mth), lambda di, bi: (bi, 0)))
         operands += (th2,)
     if use_lamw:
-        in_specs.append(pl.BlockSpec((1, 1), lambda di, bi: (0, 0),
-                                     memory_space=pltpu.SMEM))
         operands += (jnp.asarray(lam, jnp.float32).reshape(1, 1),)
-    sides = []
-    if fwd:
-        sides.append((pl.BlockSpec((block_b, mw), lambda di, bi: (bi, 0)),
-                      jax.ShapeDtypeStruct((bp, mw), jnp.float32),
-                      pltpu.VMEM((bp, mw), jnp.float32) if z_acc_used
-                      else None))
-    if bwd:
-        sides.append((pl.BlockSpec((block_d, mth), lambda di, bi: (di, 0)),
-                      jax.ShapeDtypeStruct((dp, mth), jnp.float32),
-                      pltpu.VMEM((block_d, mth), jnp.float32) if g_acc_used
-                      else None))
-    outs = pl.pallas_call(
-        kernel,
-        grid=(nd, nb),
-        in_specs=in_specs,
-        out_specs=[s[0] for s in sides],
-        out_shape=[s[1] for s in sides],
-        scratch_shapes=[s[2] for s in sides if s[2] is not None],
-        interpret=interpret,
-        name=f"vfl_grad_{mode}",    # the kernel's name in a device trace
-    )(*operands)
+    outs = _invoke(call, operands)
     if not fwd:
         z = None
     elif split is None:

@@ -143,6 +143,33 @@ def test_is_finite_declassification():
     assert flagged.get(UNMASKED, 0) >= 1
 
 
+def test_taint_sees_through_the_kernel_wrapper():
+    """The kernel call sits inside its batching wrapper's ``call`` jaxpr:
+    the taint pass walks into it, so a party's raw partial from the
+    kernel still flags at the boundary, and its finiteness verdict alone
+    stays clean."""
+    from repro.kernels import vfl_grad as vg
+
+    def partial(x, w):
+        return vg.vfl_grad(x, w, None, mode="forward", interpret=True)[0]
+
+    def raw_leak(x, w):
+        return jax.lax.psum(partial(x, w), "model")
+
+    def health_only(x, w):
+        ok = jnp.all(jnp.isfinite(partial(x, w))).astype(jnp.float32)
+        return jax.lax.psum(ok, "model")
+
+    args = (jnp.ones((8, 5)), jnp.ones((5,)))
+    axis_env = [("model", 4)]
+    jx = jax.make_jaxpr(raw_leak, axis_env=axis_env)(*args)
+    assert analysis.count_primitive(jx, "custom_vmap_call") == 1
+    found = analyze_party_jaxpr(jx, [0], axis="model")
+    assert finding_codes(found).get(UNMASKED, 0) >= 1
+    jx2 = jax.make_jaxpr(health_only, axis_env=axis_env)(*args)
+    assert finding_codes(analyze_party_jaxpr(jx2, [0], axis="model")) == {}
+
+
 def test_guarded_entries_lint_like_faulted(quick_reports):
     """Guarded epochs are membership-varying (the quarantine drops
     parties), so they must be analyzed with mask re-keying required."""

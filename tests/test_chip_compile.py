@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis.walkers import primitive_eqns
 from repro.core import algorithms, losses
 from repro.core.engine import EngineConfig, FusedEngine
 from repro.kernels import vfl_grad as vg
@@ -125,3 +126,55 @@ def test_fused_sgd_epoch_compiles_for_v5e(one_chip):
         _spec(one_chip, ()), _spec(one_chip, (2,), jnp.uint32),
         batch=batch, steps=n // batch).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (q, B, D, mode, Mw, Mθ, split, traced λ) — the grouped call that the
+# one-chip engine makes under its vmap over parties: D4's 4 parties of 64
+# columns (padded to 128 lanes) at SVRG's rank 2, D2's 2 parties of 46
+GROUPED_CASES = {
+    "d4_forward": (4, 64, 64, "forward", 2, None, None, False),
+    "d4_backward_no_w": (4, 64, 64, "backward", None, 2, None, False),
+    "d4_fused_lambda": (4, 64, 64, "fused", 2, 2, None, True),
+    "d4_split": (4, 128, 64, "fused", 1, 1, 64, False),
+    "d2_forward": (2, 64, 46, "forward", 1, None, None, False),
+    "d2_backward_no_w": (2, 64, 46, "backward", None, 1, None, False),
+    "d2_fused": (2, 64, 46, "fused", 1, 1, None, False),
+    "d2_split": (2, 128, 46, "fused", 1, 1, 64, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_grouped_vfl_grad_compiles_for_v5e(one_chip, case):
+    """``jax.vmap`` over parties compiles to one Mosaic call whose X
+    operand keeps the party axis in front, with no grid visit per party."""
+    q, b, d, mode, mw, mth, split, traced_lam = GROUPED_CASES[case]
+    rows_th = split if split is not None else b
+    args = [_spec(one_chip, (q, b, d))]
+    if mw is not None:
+        args.append(_spec(one_chip, (q, d, mw)))
+    if mth is not None:
+        args.append(_spec(one_chip, (q, rows_th, mth)))
+    if traced_lam:
+        args.append(_spec(one_chip, ()))
+
+    def call(*ops):
+        it = iter(ops)
+        xb = next(it)
+        w = next(it) if mw is not None else None
+        th = next(it) if mth is not None else None
+        lam = next(it) if traced_lam else None
+
+        def party(x, wp, tp):
+            return vg.vfl_grad(x, wp, tp, 0.0 if lam is None else lam,
+                               mode=mode, split=split, interpret=False)
+        return jax.vmap(party)(xb, w, th)
+
+    [eqn] = primitive_eqns(jax.make_jaxpr(call)(*args), "pallas_call")
+    gm = eqn.params["grid_mapping"]
+    assert len(gm.grid) == 2            # (nD, nB): no axis of parties
+    assert all(getattr(bm.block_shape[0], "block_size", None) == q
+               for bm in gm.block_mappings)
+    text = jax.jit(call).lower(*args).compile().as_text()
+    [kernel] = [ln for ln in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln]
+    assert f"f32[{q},{b},128]" in kernel

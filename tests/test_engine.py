@@ -541,6 +541,55 @@ def test_pipelined_one_kernel_invocation_per_step(ds, layout, prob):
     assert scan_body_primitive_counts(jx_seq, "pallas_call") == [2]
 
 
+def _kernel_blocks(jx):
+    """(grid, leading block dim of X) of every kernel call in a jaxpr."""
+    from repro.analysis.walkers import primitive_eqns
+    out = []
+    for eqn in primitive_eqns(jx, "pallas_call"):
+        gm = eqn.params["grid_mapping"]
+        lead = gm.block_mappings[0].block_shape
+        out.append((gm.grid, getattr(lead[0], "block_size", None)
+                    if len(lead) == 3 else None))
+    return out
+
+
+@pytest.mark.parametrize("pmesh", [None, "packed"])
+def test_one_chip_kernel_calls_hold_all_parties(ds, layout, prob, pmesh):
+    """The one-chip engine calls the kernel under its vmap over parties
+    (and, packed, a vmap over slots around one over each slot's parties):
+    each scanned call of the SGD and SVRG steps is one call whose blocks
+    hold all q = 8 parties, with no grid axis over them."""
+    from repro.sharding.api import PartyMesh
+    mesh = PartyMesh(q=8, slots=2) if pmesh else None
+    key = jax.random.PRNGKey(27)
+    eng = FusedEngine(prob, ds.x_train, ds.y_train, layout,
+                      EngineConfig(secure="off", use_kernel=True), mesh=mesh)
+    wq = eng.pack_w(np.zeros(D))
+    jx_sgd = eng.sgd_epoch_jaxpr(wq, 0.3, key, BATCH, 8)
+    mu = eng.full_gradient(wq, key)
+    eng.svrg_epoch(wq, wq, mu, 0.3, key, BATCH, 8)
+    jx_svrg = jax.make_jaxpr(
+        lambda xs, w: eng._jitted["svrg"](xs, w, w, mu, eng.maskq, eng.y,
+                                          0.3, key, BATCH, 8))(eng.xs, wq)
+    for jx in (jx_sgd, jx_svrg):
+        assert scan_body_primitive_counts(jx, "pallas_call") == [2]
+        assert _kernel_blocks(jx) == [((1, 1), 8)] * 2
+
+
+def test_flat_mesh_kernel_calls_are_per_party(ds, prob):
+    """A flat mesh binds the parties with ``shard_map``, not vmap: each
+    device's call keeps one party's 2-D blocks (a one-device mesh here)."""
+    from jax.sharding import Mesh
+    lay = algorithms.PartyLayout.even(D, 1, 1)
+    eng = FusedEngine(prob, ds.x_train, ds.y_train, lay,
+                      EngineConfig(secure="off", use_kernel=True),
+                      mesh=Mesh(np.array(jax.devices()[:1]), ("model",)))
+    jx = eng.sgd_epoch_jaxpr(eng.pack_w(np.zeros(D)), 0.3,
+                             jax.random.PRNGKey(28), BATCH, 8)
+    assert scan_body_primitive_counts(jx, "pallas_call") == [2]
+    assert _kernel_blocks(jx) == [((1, 1), None)] * 2
+
+
 def test_pipelined_delayed_matches_oracle(ds, layout, prob):
     tau, lr, epochs, seed = 4, 0.3, 3, 0
     delays = staleness.party_delays(layout, D, tau, seed=seed)

@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st
 
+from repro.analysis.walkers import primitive_eqns
 from repro.kernels import ops, ref
+from repro.kernels import vfl_grad as vg
 
 
 def _rand(key, shape, dtype):
@@ -349,6 +351,85 @@ def test_vfl_grad_partials_are_party_blocks():
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(z0 + z1), np.asarray(z_full),
                                atol=1e-4, rtol=1e-4)
+
+
+def _party_call(mode, m, tiles):
+    """One party's kernel call in ``mode``, rank ``m``; ``tiles`` (8-row,
+    8-lane blocks) forces several grid visits and both accumulators."""
+    blocks = dict(block_b=8, block_d=8) if tiles else {}
+
+    def call(x, w, th):
+        if mode == "forward":
+            return vg.vfl_grad(x, w, None, mode="forward", interpret=True,
+                               **blocks)[0]
+        if mode == "backward":
+            return vg.vfl_grad(x, None, th, mode="backward", denom=7,
+                               interpret=True, **blocks)[1]
+        if mode == "fused":
+            return vg.vfl_grad(x, w, th, 0.03, interpret=True, **blocks)
+        return vg.vfl_grad(x, w, th[:11], split=11, interpret=True,
+                           **blocks)
+    return call
+
+
+@pytest.mark.parametrize("m", [1, 2])
+@pytest.mark.parametrize("q", [1, 2, 3, 4])
+@pytest.mark.parametrize("mode", ["forward", "backward", "fused", "split"])
+def test_vfl_grad_grouped_equals_per_party(mode, q, m):
+    """Under ``jax.vmap`` over parties the kernel is ONE call whose blocks
+    hold the parties; it returns what one call per party returns, to f32
+    rounding: the same dots run, but XLA compiles the interpreted 2-D and
+    3-D bodies apart, and may order a dot's sums or fuse λw's multiply-add
+    differently in each.
+    Odd party widths (13 columns) and rows (23) take the pad path; M = 2
+    also runs on 8 × 8 tiles, so the accumulators of both sides are on."""
+    ks = jax.random.split(jax.random.PRNGKey(40 + q), 3)
+    xs = _rand(ks[0], (q, 23, 13), jnp.float32)
+    ws = _rand(ks[1], (q, 13, m), jnp.float32)
+    ths = _rand(ks[2], (q, 23, m), jnp.float32)
+    call = jax.jit(_party_call(mode, m, tiles=m == 2))
+    grouped = jax.jit(jax.vmap(call))(xs, ws, ths)
+    [eqn] = primitive_eqns(jax.make_jaxpr(jax.vmap(call))(xs, ws, ths),
+                           "pallas_call")
+    assert eqn.params["grid_mapping"].block_mappings[0] \
+        .block_shape[0].block_size == q
+    for p in range(q):
+        one = call(xs[p], ws[p], ths[p])
+        for a, b in zip(jax.tree.leaves(grouped), jax.tree.leaves(one)):
+            np.testing.assert_allclose(np.asarray(a[p]), np.asarray(b),
+                                       rtol=1e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("mode", ["forward", "backward", "fused", "split"])
+def test_vfl_grad_grouped_nested_vmaps(mode):
+    """A packed PartyMesh emulation nests vmaps: slots around packed
+    parties, inside a data axis whose operands are partly unbatched.  Every
+    level folds into the one call's party axis (3 × 2 × 2 = 12 parties),
+    and each party's outputs equal its own call's."""
+    ks = jax.random.split(jax.random.PRNGKey(50), 4)
+    xs = _rand(ks[0], (2, 2, 23, 13), jnp.float32)      # (slots, pps, ...)
+    ws = _rand(ks[1], (2, 2, 13, 2), jnp.float32)
+    ths = _rand(ks[2], (2, 2, 23, 2), jnp.float32)
+    scale = 1.0 + jnp.arange(3, dtype=jnp.float32)      # the data axis
+    call = jax.jit(_party_call(mode, 2, tiles=False))
+
+    def sharded(s):        # the data axis batches X alone
+        return jax.vmap(jax.vmap(call))(xs * s, ws, ths)
+
+    out = jax.jit(jax.vmap(sharded))(scale)
+    [eqn] = primitive_eqns(jax.make_jaxpr(jax.vmap(sharded))(scale),
+                           "pallas_call")
+    gm = eqn.params["grid_mapping"]
+    assert len(gm.grid) == 2
+    assert gm.block_mappings[0].block_shape[0].block_size == 12
+    for i in range(3):
+        for s in range(2):
+            for p in range(2):
+                one = call(xs[s, p] * scale[i], ws[s, p], ths[s, p])
+                for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(one)):
+                    np.testing.assert_allclose(np.asarray(a[i, s, p]),
+                                               np.asarray(b), rtol=1e-6,
+                                               atol=2e-6)
 
 
 @pytest.mark.parametrize("pos,off,win", [(300, 0, None), (300, 0, 128),

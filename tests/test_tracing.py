@@ -197,3 +197,59 @@ def test_engine_build_dispatch_and_loads_are_recorded():
     [(o, _)] = by_name["vfb2.objective"]
     assert [s.parent for _, s in by_name["vfb2.objective.enqueue"]] == [o]
     assert [s.parent for _, s in by_name["vfb2.objective.fetch"]] == [o]
+
+
+def test_one_chip_svrg_books_grouped_kernel_calls():
+    """The one-chip engine calls the kernel under its vmap over parties:
+    each call is built with the parties inside its blocks, and counted as
+    such under the program that traced it."""
+    eng = _engine(use_kernel=True)
+    wq = eng.pack_w(np.zeros(D, np.float32))
+    key = jax.random.PRNGKey(3)
+    with tracing.Recorder() as rec:
+        mu = eng.full_gradient(wq, key)
+        eng.svrg_epoch(wq, wq, mu, 0.1, key, BATCH, STEPS)
+    # forward at both iterates and the backward: one call each (M = 2)
+    assert rec.total("vfb2.kernel.grouped", "svrg") == 2
+    assert rec.total("vfb2.kernel.per_party") == 0
+
+
+@pytest.mark.parametrize("q,party_mib,expect", [
+    (4, 1, 4),      # all four fit half the default scope
+    (4, 3, 2),      # four do not, two do
+    (4, 5, 1),      # not even two: one party a visit
+    (3, 3, 1),      # three do not fit, and 3's only smaller divisor is 1
+    (6, 3, 2),      # the largest divisor of 6 that fits
+])
+def test_parties_per_visit_from_shapes(q, party_mib, expect):
+    from repro.kernels import vfl_grad as vg
+    assert vg.VMEM_BUDGET == 8 << 20
+    assert vg.parties_per_visit(q, party_mib << 20) == expect
+
+
+@pytest.mark.parametrize("rows,parties,kind", [
+    (64, 4, "grouped"),         # D4's step: 4 × 0.25 MiB
+    (4096, 2, "grouped"),       # 4 × 2.4 MiB (the z accumulator) > 8 MiB
+    (16384, 1, "per_party"),    # one party's accumulator alone is 8 MiB
+])
+def test_vmem_budget_sets_parties_per_visit(rows, parties, kind):
+    """Q comes from the block shapes alone: traced abstractly (nothing of
+    this size is allocated), a forward call over 1,024 columns per party
+    whose z accumulator outgrows the budget holds fewer parties a visit."""
+    from repro.analysis.walkers import primitive_eqns
+    from repro.kernels import vfl_grad as vg
+    x = jax.ShapeDtypeStruct((4, rows, 1024), np.float32)
+    w = jax.ShapeDtypeStruct((4, 1024, 32), np.float32)
+
+    def party(xb, wb):
+        return vg.vfl_grad(xb, wb, None, mode="forward", interpret=False)[0]
+
+    with tracing.Recorder() as rec:
+        jx = jax.make_jaxpr(jax.vmap(party))(x, w)
+    assert {k: rec.total(f"vfb2.kernel.{k}")
+            for k in ("grouped", "per_party")} == {
+                k: int(k == kind) for k in ("grouped", "per_party")}
+    [eqn] = primitive_eqns(jx, "pallas_call")
+    gm = eqn.params["grid_mapping"]
+    assert gm.block_mappings[0].block_shape[0].block_size == parties
+    assert gm.grid[:-2] == ((4 // parties,) if parties < 4 else ())
