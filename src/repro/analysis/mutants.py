@@ -1,9 +1,9 @@
 """Known-bad aggregation mutants — the analyzer's self-test.
 
 A static analyzer that never fires is worse than none, so the lint run
-opens by analyzing three *deliberately broken* aggregation kernels (each
-a realistic way to get Algorithm 1 wrong) plus two shipped-secure
-positive controls.  The gate: every mutant must produce its named
+opens by analyzing *deliberately broken* aggregation kernels (each a
+realistic way to get Algorithm 1 wrong) plus shipped-secure positive
+controls.  The gate: every mutant must produce its named
 finding and every control must be clean — otherwise the analyzer itself
 is broken and the matrix results are meaningless.
 
@@ -25,7 +25,13 @@ The mutants:
   the same inner position of different slots share a mask stream, so the
   key is not distinct per logical party → ``mask-not-party-distinct``
   under the two-axis boundary rule (the matching positive control is the
-  shipped ``secure_psum_hier``, which folds both levels).
+  shipped ``secure_psum_hier``, which folds both levels);
+* ``predrawn_equal_seeded`` — an epoch's step masks drawn before its
+  scan in one batched op, as the engine draws them, but from the step
+  keys alone (no ``fold_in(axis_index)``): the stream reaches the scan
+  body as a scan input and every party adds the same δ[t] →
+  ``mask-not-party-distinct`` (the matching positive control draws with
+  the shipped ``two_tree_mask`` and reduces with ``two_tree_apply``).
 """
 from __future__ import annotations
 
@@ -42,6 +48,7 @@ from repro.core import secure_agg
 AXIS = "model"
 Q = 4
 _SHAPE = (8,)
+_STEPS = 3
 
 # hierarchical packing self-test: q = SLOTS × PPS logical parties over
 # the (outer slot axis, inner vmapped party axis) pair
@@ -115,6 +122,30 @@ def control_hier(z, key):
                                        slots=SLOTS, pps=PPS)
 
 
+def _scan_predrawn(zs, deltas):
+    """The engine's pre-drawn epoch shape: step t reduces z[t] masked by
+    the scan input δ[t] (two-tree: ξ₁ − ξ₂)."""
+    def step(_, inp):
+        z, d = inp
+        return None, secure_agg.two_tree_apply(z, d, AXIS)
+    return jax.lax.scan(step, None, (zs, deltas))[1]
+
+
+def predrawn_equal_seeded(zs, keys):
+    """Mutant: the epoch's step masks drawn up front from the step keys,
+    with no party index folded in."""
+    deltas = jax.vmap(
+        lambda k: jax.random.normal(k, zs.shape[1:], jnp.float32))(keys)
+    return _scan_predrawn(zs, deltas)
+
+
+def control_predrawn(zs, keys):
+    """Positive control: the engine's pre-drawn two-tree step masks."""
+    deltas = jax.vmap(
+        lambda k: secure_agg.two_tree_mask(k, AXIS, zs.shape[1:]))(keys)
+    return _scan_predrawn(zs, deltas)
+
+
 @dataclasses.dataclass
 class MutantResult:
     name: str
@@ -138,6 +169,8 @@ def run_selftest() -> List[MutantResult]:
     z = jnp.zeros(_SHAPE, jnp.float32)
     key = jax.random.key(0)
     alive = jnp.float32(1.0)
+    zs = jnp.zeros((_STEPS,) + _SHAPE, jnp.float32)
+    keys = jax.random.split(key, _STEPS)
     cases = [
         ("off_psum", _trace(off_psum, z), False, {UNMASKED: 1}),
         ("equal_seeded", _trace(equal_seeded, z, key), False,
@@ -146,6 +179,9 @@ def run_selftest() -> List[MutantResult]:
         ("control_two_tree", _trace(control_two_tree, z, key), False, {}),
         ("control_ring_members", _trace(control_ring_members, z, key, alive),
          True, {}),
+        ("predrawn_equal_seeded", _trace(predrawn_equal_seeded, zs, keys),
+         False, {EQUAL_SEEDED: 1}),
+        ("control_predrawn", _trace(control_predrawn, zs, keys), False, {}),
     ]
     hier_cases = [
         ("hier_inner_only", _trace_hier(hier_inner_only, z, key), False,

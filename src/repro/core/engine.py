@@ -30,8 +30,11 @@ ways:
 Secure aggregation inside the scan uses the same primitives as the rest of
 the repo: ``secure_psum`` (two-tree masks, Algorithm 1), ``secure_psum_ring``
 (pairwise-cancelling ring masks, §Perf), or a plain ``psum`` (``"off"``,
-the losslessness oracle).  Labels are replicated across parties here — the
-SPMD stand-in for the dominator broadcasting ϑ, numerically identical.
+the losslessness oracle).  A fault-free flat epoch draws all of its
+steps' masks before the scan, in one batched op from the same step keys
+(``FusedEngine._masks``), and each step only adds its δ and reduces.
+Labels are replicated across parties here — the SPMD stand-in for the
+dominator broadcasting ϑ, numerically identical.
 
 Multi-dominator epochs
 ----------------------
@@ -126,11 +129,13 @@ from repro import tracing
 from repro.core.algorithms import PartyLayout, _batch_indices
 from repro.core.faults import HealthStats, apply_corruption
 from repro.core.losses import Problem
-from repro.core.secure_agg import (secure_psum, secure_psum_hier,
+from repro.core.secure_agg import (ring_apply, ring_mask,
+                                   secure_psum, secure_psum_hier,
                                    secure_psum_hier_members,
                                    secure_psum_members,
                                    secure_psum_ring,
-                                   secure_psum_ring_members)
+                                   secure_psum_ring_members,
+                                   two_tree_apply, two_tree_mask)
 from repro.kernels import vfl_grad as _vg
 from repro.sharding.api import PartyMesh, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -213,6 +218,31 @@ def _scoped(name: str):
                 return fn(*args, **kwargs)
         return scoped
     return wrap
+
+
+#: bytes an epoch's pre-drawn step masks may take on one device (steps ×
+#: the device's parties × |z| × 4); a larger stream is drawn step by step
+MASK_STREAM_BUDGET = 64 << 20
+
+
+@jax.tree_util.register_pytree_node_class
+class _Masks:
+    """An epoch's step masks δ, drawn before its scan (leading axis: the
+    step).  The scan takes it where it would take the step keys, and an
+    index selects steps as it does on the keys."""
+
+    def __init__(self, delta):
+        self.delta = delta
+
+    def __getitem__(self, i):
+        return _Masks(self.delta[i])
+
+    def tree_flatten(self):
+        return (self.delta,), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
 
 
 @_scoped("vfb2.sample")
@@ -705,16 +735,55 @@ class FusedEngine:
         return xb_fwd @ wp, gg
 
     @_scoped("vfb2.aggregate")
+    def _masks(self, mkeys, shape, sliced: bool = False):
+        """What an epoch's scan takes for its steps' masks: the whole
+        stream of step masks δ for partials of ``shape``, drawn here in
+        one batched op from the step keys ``mkeys`` (the values each step
+        would draw itself), or the keys where the step must draw: secure
+        off, the packed layout (two salted levels) or a stream over
+        :data:`MASK_STREAM_BUDGET`.  ``sliced``: the epoch aggregates its
+        data shard's slice, so each key first folds in the shard
+        (:meth:`_dkey`)."""
+        cfg = self.cfg
+        if sliced:
+            mkeys = jax.vmap(self._dkey)(mkeys)
+        local = 1 if self._use_shard_map else self._slots * self._ddp
+        nbytes = mkeys.shape[0] * local * int(np.prod(shape)) * 4
+        if (cfg.secure == "off" or self._pps > 1
+                or nbytes > MASK_STREAM_BUDGET):
+            return mkeys
+        # drawn flat, the values a draw of ``shape`` gives in row-major
+        # order: a narrow trailing axis (SVRG's 2 columns) would pad to the
+        # chip's 128 lanes in every step's slice of the stream
+        size = (int(np.prod(shape)),)
+        draw = ring_mask if cfg.secure == "ring" else two_tree_mask
+        return _Masks(jax.vmap(
+            lambda k: draw(k, cfg.axis, size, cfg.mask_scale))(mkeys))
+
+    @_scoped("vfb2.aggregate")
     def _agg(self, z, kt):
         """Masked secure aggregation of partials over the party axis.
 
-        Flat layout: one reduction over ``cfg.axis``.  PartyMesh packed
-        layout: the hierarchical two-level form — intra-slot reduce over
-        the inner vmapped party axis, then the configured two_tree/ring
-        lowering across slots, with every mask stream ``fold_in``-
-        distinct per *logical* party (see ``secure_psum_hier``).
+        ``kt`` is the step's mask key, or its pre-drawn mask from
+        :meth:`_masks`.  Flat layout: one reduction over ``cfg.axis``.
+        PartyMesh packed layout: the hierarchical two-level form —
+        intra-slot reduce over the inner vmapped party axis, then the
+        configured two_tree/ring lowering across slots, with every mask
+        stream ``fold_in``-distinct per *logical* party (see
+        ``secure_psum_hier``).  Each masked aggregation is counted at
+        trace time as ``vfb2.masks.predrawn`` or ``.per_step``.
         """
         cfg = self.cfg
+        if isinstance(kt, _Masks):
+            tracing.count("vfb2.masks.predrawn")
+            delta = kt.delta.reshape(z.shape)
+            if cfg.secure == "ring":
+                return ring_apply(z, delta, cfg.axis)
+            return two_tree_apply(z, delta, cfg.axis,
+                                  schedule_faithful=cfg.schedule_faithful,
+                                  q=self.q)
+        if cfg.secure != "off":
+            tracing.count("vfb2.masks.per_step")
         if self._pps > 1:
             if cfg.secure == "off":
                 return jax.lax.psum(z, self._party_axes)
@@ -748,6 +817,8 @@ class FusedEngine:
         above both levels (``secure_psum_hier_members``).
         """
         cfg = self.cfg
+        if cfg.secure != "off":
+            tracing.count("vfb2.masks.per_step")
         if self._pps > 1:
             if cfg.secure == "off":
                 return jax.lax.psum(alive * z, self._party_axes)
@@ -839,6 +910,8 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, (idx.shape[1] // self._ddp,),
+                                    sliced=True)
 
                 def body(wp, inp):
                     ib, kt = inp
@@ -849,14 +922,14 @@ class FusedEngine:
                     ibs = self._dslice(ib)
                     xb = self._rows(xp, ibs)
                     z = self._fwd(xb, wp[:, None])[:, 0]
-                    agg = self._agg(z, self._dkey(kt))
+                    agg = self._agg(z, kt)
                     theta = prob.theta(agg, y[ibs])
                     g = self._dsum(
                         self._bwd(xb, theta[:, None], ib.shape[0]))[:, 0] \
                         + prob.lam * prob.reg_grad(wp)
                     return wp - lr * maskp * g, None
 
-                wp, _ = jax.lax.scan(body, wp, (idx, mkeys))
+                wp, _ = jax.lax.scan(body, wp, (idx, masks))
                 return wp
 
             mapped = self._bind(party)
@@ -907,13 +980,15 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, wsp, mup, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, (idx.shape[1] // self._ddp, 2),
+                                    sliced=True)
 
                 def body(wp, inp):
                     ib, kt = inp
                     ibs = self._dslice(ib)
                     xb = self._rows(xp, ibs)
                     z = self._fwd(xb, jnp.stack([wp, wsp], axis=1))  # (B, 2)
-                    agg = self._agg(z, self._dkey(kt))
+                    agg = self._agg(z, kt)
                     th1 = prob.theta(agg[:, 0], y[ibs])
                     th0 = prob.theta(agg[:, 1], y[ibs])
                     gg = self._dsum(
@@ -923,7 +998,7 @@ class FusedEngine:
                     g0 = gg[:, 1] + prob.lam * prob.reg_grad(wsp)
                     return wp - lr * maskp * (g1 - g0 + mup), None
 
-                wp, _ = jax.lax.scan(body, wp, (idx, mkeys))
+                wp, _ = jax.lax.scan(body, wp, (idx, masks))
                 return wp
 
             mapped = self._bind(party)
@@ -976,6 +1051,7 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, tab, avgp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 n = y.shape[0]
 
                 def body(carry, inp):
@@ -998,7 +1074,7 @@ class FusedEngine:
                     return (wp, tab, avgp), None
 
                 (wp, tab, avgp), _ = jax.lax.scan(body, (wp, tab, avgp),
-                                                  (idx, mkeys))
+                                                  (idx, masks))
                 return wp, tab, avgp
 
             mapped = self._bind(party)
@@ -1032,6 +1108,7 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:])
 
                 def body(wp, inp):
                     ibf, kt = inp                 # ibf: (m·B,) concatenated
@@ -1044,7 +1121,7 @@ class FusedEngine:
                     g = gg.sum(axis=1) + m * prob.lam * prob.reg_grad(wp)
                     return wp - lr * maskp * g, None
 
-                wp, _ = jax.lax.scan(body, wp, (idx, mkeys))
+                wp, _ = jax.lax.scan(body, wp, (idx, masks))
                 return wp
 
             mapped = self._bind(party)
@@ -1074,6 +1151,7 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, wsp, mup, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:] + (2,))
 
                 def body(wp, inp):
                     ibf, kt = inp
@@ -1089,7 +1167,7 @@ class FusedEngine:
                         + mup)
                     return wp - lr * maskp * v, None
 
-                wp, _ = jax.lax.scan(body, wp, (idx, mkeys))
+                wp, _ = jax.lax.scan(body, wp, (idx, masks))
                 return wp
 
             mapped = self._bind(party)
@@ -1118,6 +1196,7 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, tab, avgp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 n = y.shape[0]
 
                 def body(carry, inp):
@@ -1139,7 +1218,7 @@ class FusedEngine:
                     return (wp, tab, avgp), None
 
                 (wp, tab, avgp), _ = jax.lax.scan(body, (wp, tab, avgp),
-                                                  (idx, mkeys))
+                                                  (idx, masks))
                 return wp, tab, avgp
 
             mapped = self._bind(party)
@@ -1175,6 +1254,7 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, buf, delay, maskp = local
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:])
 
                 def body(carry, inp):
                     wp, buf, t = carry
@@ -1195,7 +1275,7 @@ class FusedEngine:
                     return (wp - lr * maskp * stale, buf, t + 1), None
 
                 (wp, buf, _), _ = jax.lax.scan(body, (wp, buf, t0),
-                                               (idx, mkeys))
+                                               (idx, masks))
                 return wp, buf
 
             mapped = self._bind(party)
@@ -1656,6 +1736,7 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, buf, delay, maskp = local    # delay: (m,)
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:])
 
                 def body(carry, inp):
                     wp, buf, t = carry
@@ -1678,7 +1759,7 @@ class FusedEngine:
                     return (wp, buf, t + 1), None
 
                 (wp, buf, _), _ = jax.lax.scan(body, (wp, buf, t0),
-                                               (idx, mkeys))
+                                               (idx, masks))
                 return wp, buf
 
             mapped = self._bind(party)
@@ -1723,10 +1804,11 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]      # prologue
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def body(carry, inp):
                     wp, xb, ib, agg = carry
@@ -1741,7 +1823,7 @@ class FusedEngine:
                     return (wp, xb_next, ib_next, agg_next), None
 
                 (wp, xb, ib, agg), _ = jax.lax.scan(
-                    body, (wp, xb0, ib0, agg0), (idx[1:], mkeys[1:]))
+                    body, (wp, xb0, ib0, agg0), (idx[1:], masks[1:]))
                 theta = prob.theta(agg, y[ib])              # epilogue
                 g = self._bwd(xb, theta[:, None], ib.shape[0])[:, 0] \
                     + prob.lam * prob.reg_grad(wp)
@@ -1772,10 +1854,11 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, wsp, mup, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:] + (2,))
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, jnp.stack([wp, wsp], axis=1))  # (B, 2)
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def update(wp, gg):
                     th_reg = prob.lam * (prob.reg_grad(wp)
@@ -1797,7 +1880,7 @@ class FusedEngine:
                     return (wp, xb_next, ib_next, agg_next), None
 
                 (wp, xb, ib, agg), _ = jax.lax.scan(
-                    body, (wp, xb0, ib0, agg0), (idx[1:], mkeys[1:]))
+                    body, (wp, xb0, ib0, agg0), (idx[1:], masks[1:]))
                 th1 = prob.theta(agg[:, 0], y[ib])          # epilogue
                 th0 = prob.theta(agg[:, 1], y[ib])
                 gg = self._bwd(xb, jnp.stack([th1, th0], axis=1),
@@ -1830,11 +1913,12 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, tab, avgp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 n = y.shape[0]
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def apply(wp, tab, avgp, raw, th_new, ib):
                     v = raw / ib.shape[0] + avgp \
@@ -1859,7 +1943,7 @@ class FusedEngine:
 
                 (wp, tab, avgp, xb, ib, agg), _ = jax.lax.scan(
                     body, (wp, tab, avgp, xb0, ib0, agg0),
-                    (idx[1:], mkeys[1:]))
+                    (idx[1:], masks[1:]))
                 th_new = prob.theta(agg, y[ib])             # epilogue
                 dth = (th_new - tab[ib])[:, None]
                 raw = self._bwd(xb, dth, 1)[:, 0]
@@ -1894,10 +1978,11 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, buf, delay, maskp = local
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def apply(wp, buf, t, g):
                     slot = t % (tau + 1)
@@ -1922,7 +2007,7 @@ class FusedEngine:
 
                 (wp, buf, t, xb, ib, agg), _ = jax.lax.scan(
                     body, (wp, buf, t0, xb0, ib0, agg0),
-                    (idx[1:], mkeys[1:]))
+                    (idx[1:], masks[1:]))
                 theta = prob.theta(agg, y[ib])              # epilogue
                 g = self._bwd(xb, theta[:, None], ib.shape[0])[:, 0] \
                     + prob.lam * prob.reg_grad(wp)
@@ -1960,10 +2045,11 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def body(carry, inp):
                     wp, xb, ibf, agg = carry
@@ -1979,7 +2065,7 @@ class FusedEngine:
                     return (wp, xb_next, ibf_next, agg_next), None
 
                 (wp, xb, ibf, agg), _ = jax.lax.scan(
-                    body, (wp, xb0, ib0, agg0), (idx[1:], mkeys[1:]))
+                    body, (wp, xb0, ib0, agg0), (idx[1:], masks[1:]))
                 b = ibf.shape[0] // m
                 theta = prob.theta(agg, y[ibf])             # epilogue
                 gg = self._bwd_doms(xb, theta, m, b)
@@ -2011,10 +2097,11 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, wsp, mup, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:] + (2,))
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, jnp.stack([wp, wsp], axis=1))
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def update(wp, gg):
                     return wp - lr * maskp * (
@@ -2037,7 +2124,7 @@ class FusedEngine:
                     return (wp, xb_next, ibf_next, agg_next), None
 
                 (wp, xb, ibf, agg), _ = jax.lax.scan(
-                    body, (wp, xb0, ib0, agg0), (idx[1:], mkeys[1:]))
+                    body, (wp, xb0, ib0, agg0), (idx[1:], masks[1:]))
                 b = ibf.shape[0] // m
                 th1 = prob.theta(agg[:, 0], y[ibf])         # epilogue
                 th0 = prob.theta(agg[:, 1], y[ibf])
@@ -2070,11 +2157,12 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, tab, avgp, maskp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 n = y.shape[0]
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def apply(wp, tab, avgp, raws, th_new, ibf):
                     b = ibf.shape[0] // m
@@ -2101,7 +2189,7 @@ class FusedEngine:
 
                 (wp, tab, avgp, xb, ibf, agg), _ = jax.lax.scan(
                     body, (wp, tab, avgp, xb0, ib0, agg0),
-                    (idx[1:], mkeys[1:]))
+                    (idx[1:], masks[1:]))
                 th_new = prob.theta(agg, y[ibf])            # epilogue
                 dth = th_new - tab[ibf]
                 raws = self._bwd_doms(xb, dth, m, 1)
@@ -2137,10 +2225,11 @@ class FusedEngine:
             def party(local, shared):
                 xp, wp, buf, delay, maskp = local    # delay: (m,)
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:])
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 z0 = self._fwd(xb0, wp[:, None])[:, 0]
-                agg0 = self._agg(z0, mkeys[0])
+                agg0 = self._agg(z0, masks[0])
 
                 def apply(wp, buf, t, gg):
                     slot = t % (tau + 1)
@@ -2167,7 +2256,7 @@ class FusedEngine:
 
                 (wp, buf, t, xb, ibf, agg), _ = jax.lax.scan(
                     body, (wp, buf, t0, xb0, ib0, agg0),
-                    (idx[1:], mkeys[1:]))
+                    (idx[1:], masks[1:]))
                 b = ibf.shape[0] // m
                 theta = prob.theta(agg, y[ibf])             # epilogue
                 gg = self._bwd_doms(xb, theta, m, b) \
@@ -2267,6 +2356,7 @@ class FusedEngine:
             def party(local, shared):
                 xp, w1, b1, w2, head, maskp, trainp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:] + head.shape)
 
                 def body(carry, inp):
                     w1, b1, w2, head = carry
@@ -2280,7 +2370,7 @@ class FusedEngine:
                     return (w1, b1, w2, head), None
 
                 carry, _ = jax.lax.scan(body, (w1, b1, w2, head),
-                                        (idx, mkeys))
+                                        (idx, masks))
                 return carry
 
             mapped = self._bind(party)
@@ -2347,6 +2437,8 @@ class FusedEngine:
                 (xp, w1, b1, w2, head, w1s, b1s, w2s, heads, mu, maskp,
                  trainp) = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys,
+                                    idx.shape[1:] + (2 * head.shape[0],))
                 mu_w1, mu_b1, mu_w2, mu_head = mu
                 hid = w1.shape[1]
                 dr = head.shape[0]
@@ -2395,7 +2487,7 @@ class FusedEngine:
                     return (w1, b1, w2, head), None
 
                 carry, _ = jax.lax.scan(body, (w1, b1, w2, head),
-                                        (idx, mkeys))
+                                        (idx, masks))
                 return carry
 
             mapped = self._bind(party)
@@ -2463,6 +2555,7 @@ class FusedEngine:
                 (xp, w1, b1, w2, head, bw1, bb1, bw2, delay, maskp,
                  trainp) = local
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:] + head.shape)
 
                 def body(carry, inp):
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
@@ -2491,7 +2584,7 @@ class FusedEngine:
 
                 (w1, b1, w2, head, bw1, bb1, bw2, _), _ = jax.lax.scan(
                     body, (w1, b1, w2, head, bw1, bb1, bw2, t0),
-                    (idx, mkeys))
+                    (idx, masks))
                 return (w1, b1, w2, head), (bw1, bb1, bw2)
 
             mapped = self._bind(party)
@@ -2987,6 +3080,7 @@ class FusedEngine:
                 (xp, w1, b1, w2, head, bw1, bb1, bw2, delay, maskp,
                  trainp) = local                      # delay: (m,)
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:] + head.shape)
 
                 def body(carry, inp):
                     w1, b1, w2, head, bw1, bb1, bw2, t = carry
@@ -3004,7 +3098,7 @@ class FusedEngine:
 
                 (w1, b1, w2, head, bw1, bb1, bw2, _), _ = jax.lax.scan(
                     body, (w1, b1, w2, head, bw1, bb1, bw2, t0),
-                    (idx, mkeys))
+                    (idx, masks))
                 return (w1, b1, w2, head), (bw1, bb1, bw2)
 
             mapped = self._bind(party)
@@ -3070,11 +3164,12 @@ class FusedEngine:
             def party(local, shared):
                 xp, w1, b1, w2, head, maskp, trainp = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys, idx.shape[1:] + head.shape)
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 u0 = self._fwd(xb0, w1)               # prologue launch
                 h0 = jnp.tanh(u0 + b1)
-                agg0 = self._agg(h0 @ w2, mkeys[0])
+                agg0 = self._agg(h0 @ w2, masks[0])
 
                 def apply(w1, b1, w2, head, g_w1, g_b1, g_w2, g_head):
                     return (w1 - lr * maskp[:, None] * g_w1,
@@ -3099,7 +3194,7 @@ class FusedEngine:
 
                 (w1, b1, w2, head, xb, ib, h, agg), _ = jax.lax.scan(
                     body, (w1, b1, w2, head, xb0, ib0, h0, agg0),
-                    (idx[1:], mkeys[1:]))
+                    (idx[1:], masks[1:]))
                 du, g_b1, g_w2, g_head = self._deep_pipe_tail(
                     h, agg, y[ib], b1, w2, head, mdom)    # epilogue
                 g_w1 = self._bwd(xb, du, 1) \
@@ -3148,6 +3243,8 @@ class FusedEngine:
                 (xp, w1, b1, w2, head, w1s, b1s, w2s, heads, mu, maskp,
                  trainp) = local
                 y, lr, idx, mkeys = shared
+                masks = self._masks(mkeys,
+                                    idx.shape[1:] + (2 * head.shape[0],))
                 mu_w1, mu_b1, mu_w2, mu_head = mu
                 hid = w1.shape[1]
                 dr = head.shape[0]
@@ -3204,7 +3301,7 @@ class FusedEngine:
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 wpair = jnp.concatenate([w1, w1s], axis=1)
-                h0, hs0, zz0 = fwd_pair(self._fwd(xb0, wpair), mkeys[0])
+                h0, hs0, zz0 = fwd_pair(self._fwd(xb0, wpair), masks[0])
 
                 def body(carry, inp):
                     w1, b1, w2, head, xb, ib, h, hs, zz = carry
@@ -3228,7 +3325,7 @@ class FusedEngine:
 
                 (w1, b1, w2, head, xb, ib, h, hs, zz), _ = jax.lax.scan(
                     body, (w1, b1, w2, head, xb0, ib0, h0, hs0, zz0),
-                    (idx[1:], mkeys[1:]))
+                    (idx[1:], masks[1:]))
                 du1, du0, v_b1, v_w2, v_head = tail(h, hs, zz, y[ib], b1,
                                                     w2, head)
                 duu = self._bwd(xb, jnp.concatenate([du1, du0], axis=1), 1)
@@ -3298,10 +3395,11 @@ class FusedEngine:
                 (xp, w1, b1, w2, head, bw1, bb1, bw2, delay, maskp,
                  trainp) = local
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:] + head.shape)
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 h0 = jnp.tanh(self._fwd(xb0, w1) + b1)
-                agg0 = self._agg(h0 @ w2, mkeys[0])
+                agg0 = self._agg(h0 @ w2, masks[0])
 
                 def ring_apply(w1, b1, w2, head, bufs, t, g_w1, g_b1,
                                g_w2, g_head):
@@ -3340,7 +3438,7 @@ class FusedEngine:
                 (w1, b1, w2, head, bw1, bb1, bw2, t, xb, ib, h, agg), _ \
                     = jax.lax.scan(
                         body, (w1, b1, w2, head, bw1, bb1, bw2, t0, xb0,
-                               ib0, h0, agg0), (idx[1:], mkeys[1:]))
+                               ib0, h0, agg0), (idx[1:], masks[1:]))
                 du, g_b1, g_w2, g_head = self._deep_pipe_tail(
                     h, agg, y[ib], b1, w2, head, 1)       # epilogue
                 g_w1 = self._bwd(xb, du, 1) + prob.lam * prob.reg_grad(w1)
@@ -3392,10 +3490,11 @@ class FusedEngine:
                 (xp, w1, b1, w2, head, bw1, bb1, bw2, delay, maskp,
                  trainp) = local                      # delay: (m,)
                 y, lr, idx, mkeys, t0 = shared
+                masks = self._masks(mkeys, idx.shape[1:] + head.shape)
                 ib0 = idx[0]
                 xb0 = self._rows(xp, ib0)
                 h0 = jnp.tanh(self._fwd(xb0, w1) + b1)
-                agg0 = self._agg(h0 @ w2, mkeys[0])
+                agg0 = self._agg(h0 @ w2, masks[0])
 
                 def ring_apply(w1, b1, w2, head, bufs, t, gw1, gb1, gw2,
                                gh):
@@ -3430,7 +3529,7 @@ class FusedEngine:
                 (w1, b1, w2, head, bw1, bb1, bw2, t, xb, ib, h, agg), _ \
                     = jax.lax.scan(
                         body, (w1, b1, w2, head, bw1, bb1, bw2, t0, xb0,
-                               ib0, h0, agg0), (idx[1:], mkeys[1:]))
+                               ib0, h0, agg0), (idx[1:], masks[1:]))
                 du, gb1, gw2, gh = self._deep_pipe_dom_tail(
                     h, agg, y[ib], b1, w2, head, m)       # epilogue
                 gw1 = self._bwd_doms_wide(xb, du, m, 1) \

@@ -14,7 +14,11 @@ Two executable forms of the same protocol:
   ``tree_psum_collective_permute`` which replays the exact T1/T2 round
   structure with ``lax.ppermute`` for schedule-faithful lowering), the
   masks are reduced over the *significantly different* T2, and the mask sum
-  is subtracted.  Output step (paper): ``wᵀx = ξ1 − ξ2``.
+  is subtracted.  Output step (paper): ``wᵀx = ξ1 − ξ2``.  Each flat form
+  is a draw (key, party index, shape → δ: ``two_tree_mask``,
+  ``ring_mask``) then an apply (partial, δ → aggregate:
+  ``two_tree_apply``, ``ring_apply``), so a scanned epoch can draw all of
+  its steps' masks in one batched op before the scan.
 
 The masking invariants the TPU form relies on — every value crossing the
 party axis is mask-offset, masks are seeded per-party-distinct
@@ -206,6 +210,34 @@ def tree_psum_collective_permute(x: jax.Array, axis_name: str,
     return acc
 
 
+def ring_mask(
+    key: jax.Array,
+    axis_name: str,
+    shape: Tuple[int, ...],
+    mask_scale: float = 1.0,
+) -> jax.Array:
+    """This party's ring mask δ_ℓ = mask_scale · (PRG(s_ℓ) − PRG(s_{ℓ−1}))
+    of ``shape`` (f32), with s_ℓ = ``fold_in(key, ℓ)``: the draw half of
+    :func:`secure_psum_ring`, callable ahead of the partial it masks."""
+    idx = jax.lax.axis_index(axis_name)
+    q = jax.lax.psum(1, axis_name)
+    r_self = jax.random.normal(jax.random.fold_in(key, idx), shape,
+                               jnp.float32)
+    r_prev = jax.random.normal(jax.random.fold_in(key, (idx - 1) % q),
+                               shape, jnp.float32)
+    return mask_scale * (r_self - r_prev)
+
+
+def ring_apply(partial: jax.Array, delta: jax.Array,
+               axis_name: str) -> jax.Array:
+    """The reduction half of :func:`secure_psum_ring`: one psum of
+    ``partial + δ`` (Σδ ≡ 0, so no mask sum is reduced)."""
+    _check_mask(partial, delta)
+    out_dtype = partial.dtype
+    masked = partial.astype(jnp.float32) + delta
+    return jax.lax.psum(masked, axis_name).astype(out_dtype)
+
+
 def secure_psum_ring(
     partial: jax.Array,
     axis_name: str,
@@ -216,7 +248,7 @@ def secure_psum_ring(
     ring masks δ_ℓ = PRG(s_ℓ) − PRG(s_{ℓ−1}) with Σ_ℓ δ_ℓ ≡ 0, so the mask
     sum never needs to be aggregated — ONE collective instead of the
     paper's two tree reductions (ξ₂ ≡ 0), halving VFL-frontend collective
-    bytes.
+    bytes.  :func:`ring_mask` then :func:`ring_apply`.
 
     Security: each seed s_ℓ is pairwise-shared between ring neighbours
     (DH-agreed in a real deployment; the SPMD simulation derives them from
@@ -226,16 +258,54 @@ def secure_psum_ring(
     collusion caveat as the paper's scheme, where Lemma 1 still protects
     the rank-1 factors.  See tests/test_security.py.
     """
-    idx = jax.lax.axis_index(axis_name)
-    q = jax.lax.psum(1, axis_name)
+    delta = ring_mask(key, axis_name, partial.shape, mask_scale)
+    return ring_apply(partial, delta, axis_name)
+
+
+def two_tree_mask(
+    key: jax.Array,
+    axis_name: str,
+    shape: Tuple[int, ...],
+    mask_scale: float = 1.0,
+) -> jax.Array:
+    """This party's Algorithm-1 mask δ = mask_scale · N(0, 1) of ``shape``
+    (f32), drawn from ``fold_in(key, axis_index)`` — per-party distinct:
+    the draw half of :func:`secure_psum`, callable ahead of the partial
+    it masks (the engine draws a whole epoch's step masks at once)."""
+    pkey = jax.random.fold_in(key, jax.lax.axis_index(axis_name))
+    return mask_scale * jax.random.normal(pkey, shape, jnp.float32)
+
+
+def two_tree_apply(
+    partial: jax.Array,
+    delta: jax.Array,
+    axis_name: str,
+    schedule_faithful: bool = False,
+    q: int | None = None,
+) -> jax.Array:
+    """The reduction half of :func:`secure_psum`: ξ₁ = Σ(z + δ) over T1,
+    ξ₂ = Σδ over T2, output ξ₁ − ξ₂ in ``partial``'s dtype.
+
+    With ``schedule_faithful=True`` the exact T1/T2 round structures are
+    replayed via ``ppermute``; otherwise both reductions lower to
+    ``lax.psum`` (XLA all-reduce) which is the production fast path — the
+    protocol security rests on masking + distinct schedules.
+    """
+    _check_mask(partial, delta)
     out_dtype = partial.dtype
-    partial = partial.astype(jnp.float32)
-    r_self = jax.random.normal(jax.random.fold_in(key, idx), partial.shape,
-                               jnp.float32)
-    r_prev = jax.random.normal(jax.random.fold_in(key, (idx - 1) % q),
-                               partial.shape, jnp.float32)
-    masked = partial + mask_scale * (r_self - r_prev)
-    return jax.lax.psum(masked, axis_name).astype(out_dtype)
+    # Mask arithmetic in f32: masking/unmasking must cancel exactly enough
+    # that the aggregate is lossless (bf16 partial + O(1) mask would lose
+    # the partial's mantissa).
+    masked = partial.astype(jnp.float32) + delta
+    if schedule_faithful:
+        nparties = q if q is not None else jax.lax.psum(1, axis_name)
+        t1, t2 = trees_lib.default_tree_pair(int(nparties))
+        xi1 = tree_psum_collective_permute(masked, axis_name, t1)
+        xi2 = tree_psum_collective_permute(delta, axis_name, t2)
+    else:
+        xi1 = jax.lax.psum(masked, axis_name)
+        xi2 = jax.lax.psum(delta, axis_name)
+    return (xi1 - xi2).astype(out_dtype)
 
 
 def secure_psum(
@@ -246,35 +316,24 @@ def secure_psum(
     schedule_faithful: bool = False,
     q: int | None = None,
 ) -> jax.Array:
-    """Masked two-tree reduction over a mesh axis (Algorithm 1 on TPU).
+    """Masked two-tree reduction over a mesh axis (Algorithm 1 on TPU):
+    :func:`two_tree_mask` then :func:`two_tree_apply`.
 
     Must be called inside ``shard_map`` (or any context where ``axis_name``
-    is bound).  ``key`` must be *per-party distinct* (fold in axis_index).
-
-    With ``schedule_faithful=True`` the exact T1/T2 round structures are
-    replayed via ``ppermute``; otherwise both reductions lower to
-    ``lax.psum`` (XLA all-reduce) which is the production fast path — the
-    protocol security rests on masking + distinct schedules, and we keep T2
-    distinct by reducing masks with a rotated ppermute ring.
+    is bound); the mask key is made per-party distinct by folding in
+    ``axis_index``.
     """
-    idx = jax.lax.axis_index(axis_name)
-    pkey = jax.random.fold_in(key, idx)
-    out_dtype = partial.dtype
-    # Mask arithmetic in f32: masking/unmasking must cancel exactly enough
-    # that the aggregate is lossless (bf16 partial + O(1) mask would lose
-    # the partial's mantissa).
-    partial = partial.astype(jnp.float32)
-    delta = mask_scale * jax.random.normal(pkey, partial.shape, jnp.float32)
-    masked = partial + delta
-    if schedule_faithful:
-        nparties = q if q is not None else jax.lax.psum(1, axis_name)
-        t1, t2 = trees_lib.default_tree_pair(int(nparties))
-        xi1 = tree_psum_collective_permute(masked, axis_name, t1)
-        xi2 = tree_psum_collective_permute(delta, axis_name, t2)
-    else:
-        xi1 = jax.lax.psum(masked, axis_name)
-        xi2 = jax.lax.psum(delta, axis_name)
-    return (xi1 - xi2).astype(out_dtype)
+    delta = two_tree_mask(key, axis_name, partial.shape, mask_scale)
+    return two_tree_apply(partial, delta, axis_name, schedule_faithful, q)
+
+
+def _check_mask(partial: jax.Array, delta: jax.Array):
+    """A mask drawn ahead of its partial must match it exactly: a
+    broadcast would mask some entries with another entry's δ."""
+    if delta.shape != partial.shape or delta.dtype != jnp.float32:
+        raise ValueError(
+            f"mask {delta.dtype}{list(delta.shape)} does not fit partial "
+            f"{partial.dtype}{list(partial.shape)} (f32 of its shape)")
 
 
 # ---------------------------------------------------------------------------

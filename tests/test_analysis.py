@@ -111,6 +111,39 @@ def test_mutant_selftest_catches_all_three():
     assert all(r.ok for r in results.values())
 
 
+def test_predrawn_mutant_flags_equal_seeded_stream():
+    """A stream drawn before the scan, from the step keys without the
+    party index, reaches the boundary through the scan input: the pass
+    follows it there and calls it equal-seeded; the shipped draw is
+    clean."""
+    results = {r.name: r for r in mu.run_selftest()}
+    assert results["predrawn_equal_seeded"].actual == {EQUAL_SEEDED: 1}
+    assert results["control_predrawn"].actual == {}
+
+
+#: the matrix's flat fault-free epochs: each draws its step masks before
+#: its scan
+PREDRAWN = ("sgd", "svrg", "saga", "multi_sgd", "pipelined_sgd",
+            f"delayed{ep.TAU}", f"multi_delayed{ep.TAU}", "deep_sgd",
+            "deep_multi_sgd", "deep_svrg", "deep_pipelined_sgd",
+            f"deep_delayed{ep.TAU}")
+
+
+@pytest.mark.parametrize("secure", ["two_tree", "ring", "two_tree_sf"])
+def test_predrawn_epochs_prove_masked_boundaries(secure):
+    """Every flat fault-free epoch of the matrix feeds its aggregations
+    from a pre-drawn stream, and the taint pass still proves each
+    boundary masked by a party-distinct stream."""
+    from repro import tracing
+    with tracing.Recorder() as rec:
+        reports = ep.analyze_matrix(secure_modes=(secure,), names=PREDRAWN)
+    assert sorted(r.name for r in reports) == sorted(PREDRAWN)
+    for r in reports:
+        assert r.taint == {}, (r.key, r.taint)
+        assert rec.total("vfb2.masks.predrawn", r.name) >= 1, r.key
+        assert rec.total("vfb2.masks.per_step", r.name) == 0, r.key
+
+
 def test_no_rekey_only_flagged_under_membership():
     # without membership semantics the per-party ring masks are fine;
     # the finding is specifically about the missing alive-set re-key

@@ -137,6 +137,91 @@ def test_secure_modes_are_lossless(ds, layout, prob, secure):
     np.testing.assert_allclose(w_sec, w_base, atol=1e-5, rtol=0)
 
 
+def _mask_epoch(eng, epoch, key, steps):
+    """One SGD or SVRG epoch from a nonzero iterate; host copy of wq."""
+    wq = eng.pack_w(np.linspace(-0.2, 0.2, D))
+    if epoch == "sgd":
+        return np.asarray(eng.sgd_epoch(wq, 0.5, key, BATCH, steps))
+    mu = eng.full_gradient(wq, key)
+    return np.asarray(eng.svrg_epoch(wq, wq, mu, 0.5, key, BATCH, steps))
+
+
+# no ring case on one party: its mask PRG(s₀) − PRG(s₀) is zero when drawn
+# once, while the per-step draw subtracts two separately fused copies of
+# one draw, which XLA's CPU backend may round apart
+MASK_CASES = [(e, s, lay, "fits") for e in ("sgd", "svrg")
+              for s in ("two_tree", "ring")
+              for lay in ("flat", "data_axis", "mesh1")
+              if (s, lay) != ("ring", "mesh1")] \
+    + [("svrg", "two_tree", "data_axis", "over")]
+
+
+@pytest.mark.parametrize("epoch,secure,lay,budget", MASK_CASES)
+def test_predrawn_masks_equal_per_step_draws(ds, layout, prob, monkeypatch,
+                                             epoch, secure, lay, budget):
+    """An epoch's step masks drawn before its scan, in one batched op, are
+    bit for bit the masks each step draws from its own key (data shard,
+    then party folded in), and the epoch's updates match the per-step
+    draw's (budget 0).  ``over``: a budget one byte short of the stream
+    takes the per-step path; the stream's own size still pre-draws.
+
+    The updates agree to f32 rounding, not to the bit: XLA's CPU backend
+    computes a per-step δ twice, in the fusion that adds it to z and in
+    the one that sums it, and the two copies may differ in the last bit;
+    a pre-drawn δ is computed once."""
+    from jax.sharding import Mesh
+
+    from repro import tracing
+    from repro.core import engine as engine_mod
+    from repro.core.secure_agg import ring_mask, two_tree_mask
+    from repro.sharding.api import PartyMesh
+    lay_, mesh, local = layout, None, 8
+    if lay == "data_axis":        # flat parties, two data shards each
+        mesh, local = PartyMesh(q=8, slots=8, data_shards=2), 16
+    elif lay == "mesh1":          # shard_map, one party on one device
+        lay_ = algorithms.PartyLayout.even(D, 1, 1)
+        mesh, local = Mesh(np.array(jax.devices()[:1]), ("model",)), 1
+    steps, key = 6, jax.random.PRNGKey(31)
+    shape = (BATCH // (2 if lay == "data_axis" else 1),) \
+        + ((2,) if epoch == "svrg" else ())
+    stream = steps * local * int(np.prod(shape)) * 4
+
+    def engine(limit):
+        monkeypatch.setattr(engine_mod, "MASK_STREAM_BUDGET", limit)
+        return FusedEngine(prob, ds.x_train, ds.y_train, lay_,
+                           EngineConfig(secure=secure), mesh=mesh)
+
+    # the stream against each step's own draw, under the epochs' binding
+    eng = engine(stream)
+    draw = ring_mask if secure == "ring" else two_tree_mask
+
+    def predrawn(local_, mkeys):
+        return eng._masks(mkeys, shape, sliced=True).delta
+
+    def per_step(local_, mkeys):
+        def step(c, kt):
+            return c, draw(eng._dkey(kt), "model", shape)
+        return jax.lax.scan(step, 0, mkeys)[1]
+
+    mkeys = eng._keys(key, steps)
+    got, want = (np.asarray(jax.jit(eng._bind(f))(eng.maskq, mkeys))
+                 for f in (predrawn, per_step))
+    assert got.shape == (lay_.q, steps, int(np.prod(shape)))
+    np.testing.assert_array_equal(got, want.reshape(got.shape))
+
+    # the epochs: which path each budget takes, and the updates
+    def run(limit):
+        with tracing.Recorder() as rec:
+            w = _mask_epoch(engine(limit), epoch, key, steps)
+        return w, (rec.total("vfb2.masks.predrawn", epoch),
+                   rec.total("vfb2.masks.per_step", epoch))
+
+    limits = (stream, 0) if budget == "fits" else (stream, stream - 1)
+    (w_pre, n_pre), (w_step, n_step) = map(run, limits)
+    assert n_pre == (1, 0) and n_step == (0, 1)
+    np.testing.assert_allclose(w_pre, w_step, rtol=0, atol=2e-7)
+
+
 def test_schedule_faithful_two_tree(ds, layout, prob):
     """T1/T2 replayed round-by-round with ppermute == all-reduce lowering."""
     key = jax.random.PRNGKey(5)

@@ -214,6 +214,49 @@ def test_one_chip_svrg_books_grouped_kernel_calls():
     assert rec.total("vfb2.kernel.per_party") == 0
 
 
+@pytest.mark.parametrize("secure", ["two_tree", "ring"])
+def test_one_chip_epochs_predraw_their_step_masks(secure):
+    """The one-chip SGD and SVRG epochs draw every step's mask before the
+    scan: each books its aggregation as ``vfb2.masks.predrawn``, and no
+    random-bit generation is left in the scan body."""
+    from repro.analysis.walkers import count_primitives, primitive_eqns
+    eng = _engine(secure)
+    wq = eng.pack_w(np.zeros(D, np.float32))
+    key = jax.random.PRNGKey(3)
+    with tracing.Recorder() as rec:
+        mu = eng.full_gradient(wq, key)
+        epochs = {
+            "sgd": lambda w: eng.sgd_epoch(w, 0.1, key, BATCH, STEPS),
+            "svrg": lambda w: eng.svrg_epoch(w, w, mu, 0.1, key, BATCH,
+                                             STEPS)}
+        jaxprs = {name: jax.make_jaxpr(f)(wq) for name, f in epochs.items()}
+    rng = {"random_bits", "threefry2x32"}
+    for name, jx in jaxprs.items():
+        assert rec.total("vfb2.masks.predrawn", name) == 1
+        assert rec.total("vfb2.masks.per_step", name) == 0
+        [scan] = primitive_eqns(jx, "scan")
+        assert count_primitives(scan.params["jaxpr"], rng) == 0
+        assert count_primitives(jx, rng) > 0       # the draw, before it
+
+
+def test_member_keyed_and_packed_aggregations_draw_per_step():
+    """Faulted and guarded epochs key each step's masks on that step's
+    alive set, and a packed PartyMesh epoch salts two stream levels:
+    all three still draw inside the step."""
+    from repro.analysis import entrypoints as ep
+    entries = {e.name: e for e in ep._entries()}
+    runs = [(ep._Fixture("two_tree"), f"faulted_sgd{ep.TAU}"),
+            (ep._Fixture("two_tree"), f"guarded_sgd{ep.TAU}_1"),
+            (ep._Fixture("two_tree", pmesh=ep.HIER), "hier_sgd")]
+    for fx, name in runs:
+        ent = entries[name]
+        prog = ent.prog or name
+        with tracing.Recorder() as rec:
+            ent.trace(fx.eng, fx)
+        assert rec.total("vfb2.masks.per_step", prog) >= 1, name
+        assert rec.total("vfb2.masks.predrawn", prog) == 0, name
+
+
 @pytest.mark.parametrize("q,party_mib,expect", [
     (4, 1, 4),      # all four fit half the default scope
     (4, 3, 2),      # four do not, two do
